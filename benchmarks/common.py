@@ -11,11 +11,16 @@ The paper evaluates pretrained 7B–70B checkpoints, which are unavailable offli
      channels of App. A / Dettmers et al. This reproduces the OPT-vs-LLaMA split:
      per-token quantization collapses on the outlier-planted model, CrossQuant holds.
 
-The trained model is cached under results/bench_model/ so re-runs are fast.
+The trained model is cached under results/bench_model/<key>/ so re-runs are fast.
+The key hashes ``BENCH_CFG``, the training steps, the seed and the source of every
+module that shapes the trained weights, so a cached checkpoint is reused only when
+the committed files would rebuild the same model.
 """
 from __future__ import annotations
 
+import hashlib
 import os
+import pathlib
 from typing import Dict, Optional
 
 import jax
@@ -40,6 +45,7 @@ BENCH_CFG = ModelConfig(
 
 VOCAB, SEQ, BATCH = 256, 64, 16
 SKEW = 0.75
+SEED = 0             # init and training-data seed of the benchmark LM
 
 
 def train_batches(step: int, *, seed: int = 0) -> Dict[str, jnp.ndarray]:
@@ -52,11 +58,21 @@ def eval_batches(n: int, *, seed: int = 10_000):
         yield train_batches(0, seed=seed + 31 * i)
 
 
+def bench_model_key(steps: int) -> str:
+    """Cache key of the trained benchmark LM: its config, steps and seed, plus the
+    source of the modules its training runs through."""
+    h = hashlib.sha256(f"{BENCH_CFG!r}|steps={steps}|seed={SEED}".encode())
+    for mod in (M, trainer, opt_lib, markov_corpus, train_batches):
+        src = getattr(mod, "__file__", None) or mod.__code__.co_filename
+        h.update(pathlib.Path(src).read_bytes())
+    return h.hexdigest()[:16]
+
+
 def get_bench_model(steps: int = 400, force: bool = False):
     """Train (or load the cached) benchmark LM. Returns (cfg, params)."""
-    cm = CheckpointManager(CACHE_DIR, keep_n=1)
+    cm = CheckpointManager(os.path.join(CACHE_DIR, bench_model_key(steps)), keep_n=1)
     cfg = BENCH_CFG
-    template = M.init_params(jax.random.PRNGKey(0), cfg)
+    template = M.init_params(jax.random.PRNGKey(SEED), cfg)
     if not force and cm.latest_step() is not None:
         params, _ = cm.restore(template)
         return cfg, params
@@ -65,7 +81,7 @@ def get_bench_model(steps: int = 400, force: bool = False):
     params = template
     opt = opt_lib.init(params)
     for s in range(steps):
-        params, opt, metrics = step_fn(params, opt, train_batches(s))
+        params, opt, metrics = step_fn(params, opt, train_batches(s, seed=SEED))
     cm.save(steps, params, blocking=True)
     print(f"# bench model trained to loss={float(metrics['loss']):.3f}")
     return cfg, params

@@ -31,9 +31,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-PEAK_INT8 = 394e12
-PEAK_BF16 = 197e12
-HBM_BW = 819e9
+from repro.launch.peaks import V5E, peaks
+
+# proj_* columns project one TPU v5e chip (CPU timings are not device numbers)
+_CHIP = peaks(V5E)
+PEAK_INT8, PEAK_BF16, HBM_BW = _CHIP.int8_ops, _CHIP.bf16_flops, _CHIP.hbm_bw
 
 # (arch tag, M=tokens-per-chip-step, K=d_model, N=output dim of the hot projection)
 SHAPES = [

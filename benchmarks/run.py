@@ -27,6 +27,8 @@ import subprocess
 import sys
 import time
 
+from repro.launch import compile_cache
+
 MODULES = [
     "table1_alpha", "table2_ppl", "table3_tasks", "fig4_kernels",
     "fig67_threshold", "fig8_alpha_sweep", "grad_compression", "qgemm_bench",
@@ -54,7 +56,10 @@ def main() -> None:
             snapshot["modules"].update(prev.get("modules", {}))
         except (OSError, json.JSONDecodeError) as e:
             print(f"# existing snapshot {args.json} unreadable ({e}); rewriting")
-    env = {**os.environ, "PYTHONPATH": os.environ.get("PYTHONPATH", "src")}
+    # children share one persistent compile cache; the parent stays off JAX
+    # (compile_cache imports it only in enable()), so a child can hold the chip
+    env = {**os.environ, "PYTHONPATH": os.environ.get("PYTHONPATH", "src"),
+           compile_cache.ENV: compile_cache.cache_dir()}
     for name in mods:
         t0 = time.time()
         print(f"== {name} ==", flush=True)

@@ -29,6 +29,7 @@ import numpy as np
 
 from repro.configs import get
 from repro.core import qlinear as ql
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.models.quantize import quantize_tree
 from repro.serving.api import AdmissionError, Request
@@ -102,6 +103,7 @@ def main() -> None:
     ap.add_argument("--kernel-stats", action="store_true",
                     help="per-request §4.1 quantization-kernel proportion")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get(args.arch, smoke=True)
     params = M.init_params(jax.random.PRNGKey(0), cfg)

@@ -72,6 +72,7 @@ import numpy as np
 from repro.configs import get
 from repro.core import calibration, kernel_analysis as KA, qlinear as ql
 from repro.data import make_train_batches
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.models.layers import QuantContext
 from repro.models.quantize import quantize_tree, quantized_bytes
@@ -281,6 +282,7 @@ def main() -> None:
                          "--mesh 4,2 or --mesh 2,2,2; needs XLA_FLAGS="
                          "--xla_force_host_platform_device_count=<product>")
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get(args.arch, smoke=True)
     params = M.init_params(jax.random.PRNGKey(0), cfg)

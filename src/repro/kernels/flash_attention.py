@@ -28,6 +28,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
+# query rows (chunk tokens × groups) per ragged-prefill grid step
+RAGGED_ROW_TILE = 128
 
 
 def _fa_kernel(q_ref, k_ref, v_ref, *refs,
@@ -95,36 +97,139 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs,
         o_ref[0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
 
 
+def _scale_rows(scale: jax.Array) -> jax.Array:
+    """(P, Hkv, ps) per-token scales → (P, 1, L) f32 rows, lane ``h·ps + t``,
+    zero-padded to L = a multiple of 128: Mosaic DMAs an HBM tile only when
+    its minor dimension is lane-aligned, and one page's scales (Hkv·ps values)
+    are fewer than 128 lanes at the engine's page sizes."""
+    P, Hkv, ps = scale.shape
+    rows = scale.astype(jnp.float32).reshape(P, 1, Hkv * ps)
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, (-(Hkv * ps)) % 128)))
+
+
+def _walk_pages(tab_ref, b, n_pages_max: int, n_live, k_hbm, v_hbm, s_hbm,
+                page_step, init, finish):
+    """Double-buffered walk over slot ``b``'s first ``n_live`` pages.
+
+    The pools stay in HBM (``memory_space=ANY``) as lane-dense (P, ps, Hkv·D)
+    tiles; while page ``j`` computes, page ``j+1``'s K/V tiles (and, int8-KV,
+    its two (1, L) scale rows from ``s_hbm``) are already in flight into the
+    spare VMEM slot. Page indices come from the scalar-prefetched flattened
+    (B·max_pages,) page table in SMEM, so the dense (B, T, Hkv, D) view is
+    never materialized and pages past ``n_live`` are never fetched.
+
+    Sentinel entries (≥ P) clamp to a valid page. For live rows the clamp is
+    unreachable below kv_len (the engine maps every valid position to a real
+    page), so the fetched bytes never contribute; a row whose table is *all*
+    sentinel (a retired slot decoding in lock-step with kv_len ≥ 1) attends
+    the clamped page and produces garbage-but-finite output — the engine
+    discards it, and the oracle's (differently-)clamped gather is equally
+    arbitrary there (pinned in tests/test_paged_serving.py).
+
+    ``page_step(j, k_tile, v_tile, scales, carry)`` gets the VMEM tile refs
+    and the (2, 1, L) scale rows (None on fp pools); ``finish(state)`` writes
+    the outputs from the final carry."""
+    P = k_hbm.shape[0]
+
+    def body(kbuf, vbuf, sbuf, sem):
+        def dmas(slot, j):
+            page = jnp.minimum(
+                tab_ref[b * n_pages_max + jnp.minimum(j, n_pages_max - 1)], P - 1)
+            copies = [
+                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot],
+                                      sem.at[slot, 0]),
+                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot],
+                                      sem.at[slot, 1]),
+            ]
+            if s_hbm is not None:
+                copies += [
+                    pltpu.make_async_copy(s_hbm[i].at[page], sbuf.at[slot, i],
+                                          sem.at[slot, 2 + i])
+                    for i in range(2)]
+            return copies
+
+        @pl.when(n_live > 0)
+        def _warmup():
+            for c in dmas(0, 0):
+                c.start()
+
+        def step(j, carry):
+            slot = jax.lax.rem(j, 2)
+
+            @pl.when(j + 1 < n_live)
+            def _prefetch():
+                for c in dmas(1 - slot, j + 1):
+                    c.start()
+
+            for c in dmas(slot, j):
+                c.wait()
+            scales = sbuf[slot] if s_hbm is not None else None
+            return page_step(j, kbuf.at[slot], vbuf.at[slot], scales, carry)
+
+        finish(jax.lax.fori_loop(0, n_live, step, init))
+
+    L = s_hbm[0].shape[-1] if s_hbm is not None else 128
+    pl.run_scoped(
+        body,
+        kbuf=pltpu.VMEM((2,) + k_hbm.shape[1:], k_hbm.dtype),
+        vbuf=pltpu.VMEM((2,) + v_hbm.shape[1:], v_hbm.dtype),
+        sbuf=pltpu.VMEM((2, 2, 1, L), jnp.float32),
+        sem=pltpu.SemaphoreType.DMA((2, 4)),
+    )
+
+
+def _online_update(carry, q, k, v, mask, ks, vs, *, scale: float,
+                   softcap: Optional[float]):
+    """One page of online softmax for one kv head: q (R, D), k/v (ps, D) f32,
+    mask (R, ps), ``ks``/``vs`` (1, ps) int8-KV per-token scales or None.
+
+    The K scale multiplies the score column and the V scale folds into the
+    probability row — the exact application points of the dense
+    ``layers.decode_attention`` int8 path (scale → softcap → mask → softmax).
+    Masked positions drop through the probability row:
+    ``p = where(mask, exp(s - m), 0)`` zeroes their l/acc contribution exactly
+    (bitwise equal to a -1e30 score mask, whose exp underflows to 0.0 in f32)."""
+    m_prev, l_prev, acc_prev = carry
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    if ks is not None:
+        s = s * ks
+    if softcap is not None:
+        s = softcap * jnp.tanh(s / softcap)
+    m_new = jnp.maximum(m_prev, jnp.max(jnp.where(mask, s, NEG_INF), axis=1))
+    p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    pv = p * vs if vs is not None else p
+    return (m_new, l_prev * corr + jnp.sum(p, axis=1),
+            acc_prev * corr[:, None] + jax.lax.dot_general(
+                pv, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32))
+
+
+def _init_carry(n_kv_heads: int, R: int, D: int):
+    return tuple((jnp.full((R,), NEG_INF, jnp.float32),
+                  jnp.zeros((R,), jnp.float32),
+                  jnp.zeros((R, D), jnp.float32))
+                 for _ in range(n_kv_heads))
+
+
+def _normalized(carry):
+    _, l, acc = carry
+    return acc / jnp.maximum(l, 1e-30)[:, None]
+
+
 def _paged_decode_kernel(tab_ref, kvlen_ref, *rest,
                          ps: int, n_pages_max: int, n_kv_heads: int,
-                         scale: float, window: Optional[int],
+                         head_dim: int, scale: float, window: Optional[int],
                          softcap: Optional[float], kv_int8: bool,
                          q_win: int = 1):
     """Decode / draft-verify attention through a page table (DESIGN.md §3.8/§3.9).
 
-    grid = (B,). The K/V pools (and, int8-KV, the per-token scale pools) stay
-    resident in HBM (``memory_space=ANY``): the kernel walks each slot's *live*
-    pages with a double-buffered async-copy pipeline — while page ``j`` computes,
-    page ``j+1``'s (ps, Hkv, D) code tile (plus its (Hkv, ps) scale tiles) is
-    already in flight into the spare VMEM slot. Page indices come from the
-    scalar-prefetched flattened (B·max_pages,) page table in SMEM, so each
-    logical page's physical tile is DMA'd straight from the pool — the dense
-    (B, T, Hkv, D) view is never materialized, and dead/sentinel pages past the
-    (B,) ``kv_len`` are never fetched at all (the loop bound is
-    ``ceil(kv_len / ps)``).
-
-    Online softmax across the page loop; the kv-head axis is a static unrolled
-    loop (decode tiles are small — one (G, ps) score tile per head per page).
-    In-page tail positions past ``kv_len`` — and, with ``window``, positions
-    that have slid out — mask through the probability row:
-    ``p = where(mask, exp(s - m), 0)`` zeroes their l/acc contribution exactly
-    (bitwise equal to a -1e30 score mask, whose exp underflows to 0.0 in f32)
-    without per-position control flow.
-
-    ``kv_int8=True``: the K scale multiplies the score column and the V scale
-    folds into the probability row — the exact application points of the dense
-    ``layers.decode_attention`` int8 path, so the fused kernel shares its
-    quantization numerics (scale → softcap → mask → softmax).
+    grid = (B,). Each slot walks its live pages (``_walk_pages``: the loop
+    bound is ``ceil(kv_len / ps)``) with an online softmax across the page
+    loop; the kv-head axis is a static unrolled loop over lane slices of the
+    lane-dense (ps, Hkv·D) page tile — one (R, ps) score tile per head per
+    page. Heads split in VMEM, never in the DMA, so D = 64 needs no padding.
 
     ``q_win > 1`` (speculative verify, DESIGN.md §3.9): q carries a draft
     window of ``q_win`` tokens per slot — rows ordered (window, group), so row
@@ -140,17 +245,12 @@ def _paged_decode_kernel(tab_ref, kvlen_ref, *rest,
     if q_win > 1:
         qlen_ref, q_ref, k_hbm, v_hbm, *refs = rest
     else:
-        qlen_ref, q_ref, k_hbm, v_hbm, refs = None, *rest[:3], rest[3:]
-    if kv_int8:
-        ks_hbm, vs_hbm, o_ref = refs
-    else:
-        o_ref, = refs
+        qlen_ref, (q_ref, k_hbm, v_hbm), refs = None, rest[:3], rest[3:]
+    s_hbm, o_ref = (refs[:2], refs[2]) if kv_int8 else (None, refs[0])
     b = pl.program_id(0)
     kvl = kvlen_ref[b]
-    n_live = pl.cdiv(kvl, ps)
-    R, D = q_ref.shape[2], q_ref.shape[3]    # R = q_win * G score-tile rows
+    R, D = q_ref.shape[2], head_dim          # R = q_win * G score-tile rows
     G = R // q_win
-    P = k_hbm.shape[0]
     if q_win > 1:
         qln = qlen_ref[b]
         # absolute position of each score-tile row's window token (clamped to
@@ -158,104 +258,38 @@ def _paged_decode_kernel(tab_ref, kvlen_ref, *rest,
         win_idx = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 0) // G
         q_pos = (kvl - qln) + jnp.minimum(win_idx, qln - 1)
 
-    def body(kbuf, vbuf, sbuf, sem):
-        def dmas(slot, j):
-            # sentinel entries (≥ P) clamp to a valid page. For live rows the
-            # clamp is unreachable below kv_len (the engine maps every valid
-            # position to a real page), so the fetched bytes never contribute;
-            # a row whose table is *all* sentinel (a retired slot decoding in
-            # lock-step with kv_len ≥ 1) attends the clamped page and produces
-            # garbage-but-finite output — the engine discards it, and the
-            # oracle's (differently-)clamped gather is equally arbitrary there
-            # (pinned in tests/test_paged_serving.py).
-            page = jnp.minimum(
-                tab_ref[b * n_pages_max + jnp.minimum(j, n_pages_max - 1)], P - 1)
-            copies = [
-                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot],
-                                      sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot],
-                                      sem.at[slot, 1]),
-            ]
-            if kv_int8:
-                copies += [
-                    pltpu.make_async_copy(ks_hbm.at[page], sbuf.at[slot, 0],
-                                          sem.at[slot, 2]),
-                    pltpu.make_async_copy(vs_hbm.at[page], sbuf.at[slot, 1],
-                                          sem.at[slot, 3]),
-                ]
-            return copies
+    def page_step(j, kt, vt, sc, carry):
+        k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
+        if q_win > 1:
+            # per-row causality: window token i attends keys ≤ its own
+            # absolute position (row 0 ≡ the single-token decode mask)
+            mask = k_pos <= q_pos
+            if window is not None:
+                mask &= (q_pos - k_pos) < window
+        else:
+            mask = k_pos < kvl
+            if window is not None:
+                # decode window semantics (layers.decode_attention): the
+                # newest token sits at kvl - 1
+                mask &= (kvl - 1 - k_pos) < window
+        out = []
+        for h in range(n_kv_heads):          # static unroll over kv heads
+            lanes, srow = slice(h * D, (h + 1) * D), slice(h * ps, (h + 1) * ps)
+            out.append(_online_update(
+                carry[h], q_ref[0, h].astype(jnp.float32),
+                kt[:, lanes].astype(jnp.float32),
+                vt[:, lanes].astype(jnp.float32), mask,
+                sc[0, :, srow] if kv_int8 else None,
+                sc[1, :, srow] if kv_int8 else None,
+                scale=scale, softcap=softcap))
+        return tuple(out)
 
-        @pl.when(n_live > 0)
-        def _warmup():
-            for c in dmas(0, 0):
-                c.start()
-
-        def page_step(j, carry):
-            slot = jax.lax.rem(j, 2)
-
-            @pl.when(j + 1 < n_live)
-            def _prefetch():
-                for c in dmas(1 - slot, j + 1):
-                    c.start()
-
-            for c in dmas(slot, j):
-                c.wait()
-            k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
-            if q_win > 1:
-                # per-row causality: window token i attends keys ≤ its own
-                # absolute position (row 0 ≡ the single-token decode mask)
-                mask = k_pos <= q_pos
-                if window is not None:
-                    mask &= (q_pos - k_pos) < window
-            else:
-                mask = k_pos < kvl
-                if window is not None:
-                    # decode window semantics (layers.decode_attention): the
-                    # newest token sits at kvl - 1
-                    mask &= (kvl - 1 - k_pos) < window
-            scales = sbuf[slot] if kv_int8 else None          # (2, Hkv, ps)
-            out = []
-            for h in range(n_kv_heads):        # static unroll over kv heads
-                m_prev, l_prev, acc_prev = carry[h]
-                q = q_ref[0, h].astype(jnp.float32)           # (R, D)
-                k = kbuf[slot, :, h, :].astype(jnp.float32)   # (ps, D)
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if kv_int8:
-                    # per-token K scale on the score column: one multiply per
-                    # (t, kv head) instead of dequantizing the (ps, D) tile
-                    s = s * scales[0, h:h + 1]                # (R, ps) * (1, ps)
-                if softcap is not None:
-                    s = softcap * jnp.tanh(s / softcap)
-                m_new = jnp.maximum(
-                    m_prev, jnp.max(jnp.where(mask, s, NEG_INF), axis=1))
-                p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-                corr = jnp.exp(m_prev - m_new)
-                v = vbuf[slot, :, h, :].astype(jnp.float32)   # (ps, D)
-                pv = p * scales[1, h:h + 1] if kv_int8 else p  # V scale → probs
-                out.append((m_new, l_prev * corr + jnp.sum(p, axis=1),
-                            acc_prev * corr[:, None] + jax.lax.dot_general(
-                                pv, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)))
-            return tuple(out)
-
-        init = tuple((jnp.full((R,), NEG_INF, jnp.float32),
-                      jnp.zeros((R,), jnp.float32),
-                      jnp.zeros((R, D), jnp.float32))
-                     for _ in range(n_kv_heads))
-        state = jax.lax.fori_loop(0, n_live, page_step, init)
+    def finish(state):
         for h in range(n_kv_heads):
-            _, l, acc = state[h]
-            o_ref[0, h] = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
+            o_ref[0, h] = _normalized(state[h]).astype(o_ref.dtype)
 
-    pl.run_scoped(
-        body,
-        kbuf=pltpu.VMEM((2,) + k_hbm.shape[1:], k_hbm.dtype),
-        vbuf=pltpu.VMEM((2,) + v_hbm.shape[1:], v_hbm.dtype),
-        sbuf=pltpu.VMEM((2, 2, n_kv_heads, ps), jnp.float32),
-        sem=pltpu.SemaphoreType.DMA((2, 4)),
-    )
+    _walk_pages(tab_ref, b, n_pages_max, pl.cdiv(kvl, ps), k_hbm, v_hbm,
+                s_hbm, page_step, _init_carry(n_kv_heads, R, D), finish)
 
 
 def paged_decode_attention_pallas(
@@ -269,26 +303,23 @@ def paged_decode_attention_pallas(
     """q: (B, Hkv, q_win·G, D); k/v pages: (P, ps, Hkv, D); page_table:
     (B, maxP) int32 (entries ≥ P are invalid — clamped in the kernel and
     masked by ``kv_len``); kv_len: (B,) int32 with kv_len ≤ maxP·ps
-    → (B, Hkv, q_win·G, D). The pools stay in HBM; the kernel DMAs each live
-    page's tile on demand (double-buffered — see ``_paged_decode_kernel``).
+    → (B, Hkv, q_win·G, D). The pools stay in HBM, viewed lane-dense as
+    (P, ps, Hkv·D); the kernel DMAs each live page's tile on demand
+    (double-buffered — see ``_walk_pages``).
 
     ``k_scale``/``v_scale`` (both or neither): int8-KV per-token scales in the
-    kernel-native (P, Hkv, ps) row layout — ``ops.paged_decode_attention``
-    transposes the engine's (P, ps, Hkv, 1) scale pools, D× smaller than the
-    code pools. Their tiles ride the same per-page DMA pipeline as the code
-    tiles and apply in-kernel at the score/prob level (dense
-    ``decode_attention`` numerics) — the int8 path never materializes a dense
-    (B, T, ...) view either.
+    (P, Hkv, ps) row layout — ``ops.paged_decode_attention`` transposes the
+    engine's (P, ps, Hkv, 1) scale pools, D× smaller than the code pools.
+    They travel as one lane-aligned row per page (``_scale_rows``) on the
+    same per-page DMA pipeline as the code tiles and apply in-kernel at the
+    score/prob level (dense ``decode_attention`` numerics) — the int8 path
+    never materializes a dense (B, T, ...) view either.
 
     ``q_win > 1`` + ``q_len`` (B,) int32: draft-window verify (DESIGN.md
     §3.9). q's third axis carries ``q_win`` window tokens × G group heads in
     (window, group) row order; ``kv_len`` counts each slot's total
     post-scatter length so window token i sits at ``kv_len - q_len + i``, and
     rows past ``q_len`` produce garbage-but-finite output the engine discards.
-
-    TPU notes: ps should be a multiple of 8 and D of 128 for native tiling
-    (int8 code pools want ps ≥ 32 sublanes); CI and the oracle-parity tests run
-    ``interpret=True`` on any backend.
     """
     B, Hkv, R, D = q.shape
     assert R % q_win == 0, (R, q_win)
@@ -301,21 +332,21 @@ def paged_decode_attention_pallas(
 
     kernel = functools.partial(
         _paged_decode_kernel, ps=ps, n_pages_max=maxP, n_kv_heads=Hkv,
-        scale=D ** -0.5, window=window, softcap=softcap, kv_int8=kv_int8,
-        q_win=q_win)
+        head_dim=D, scale=D ** -0.5, window=window, softcap=softcap,
+        kv_int8=kv_int8, q_win=q_win)
     n_pref = 2 if q_win == 1 else 3
     qmap = lambda b, *pref: (b, 0, 0, 0)
     in_specs = [
         pl.BlockSpec((1, Hkv, R, D), qmap),
-        pl.BlockSpec(memory_space=pltpu.ANY),        # k pool, paged via DMA
-        pl.BlockSpec(memory_space=pltpu.ANY),        # v pool
+        pl.BlockSpec(memory_space=pl.ANY),        # k pool, paged via DMA
+        pl.BlockSpec(memory_space=pl.ANY),        # v pool
     ]
-    args = [q, k_pages, v_pages]
+    args = [q, k_pages.reshape(P, ps, Hkv * D), v_pages.reshape(P, ps, Hkv * D)]
     if kv_int8:
         assert k_scale.shape == v_scale.shape == (P, Hkv, ps), (
             k_scale.shape, (P, Hkv, ps))
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,
         grid=(B,),
@@ -334,165 +365,85 @@ def paged_decode_attention_pallas(
     )(*pref, *args)
 
 
-def _ragged_prefill_kernel(tab_ref, qstart_ref, qlen_ref, kvlen_ref,
+def _ragged_prefill_kernel(tab_ref, qlen_ref, kvlen_ref,
                            q_ref, kn_ref, vn_ref, k_hbm, v_hbm, *refs,
                            ps: int, n_pages_max: int, n_kv_heads: int,
-                           n_groups: int, chunk_cap: int, scale: float,
-                           window: Optional[int], softcap: Optional[float],
-                           kv_int8: bool):
+                           n_groups: int, head_dim: int, row_tile: int,
+                           scale: float, window: Optional[int],
+                           softcap: Optional[float], kv_int8: bool):
     """Ragged chunked-prefill attention over the paged pool (DESIGN.md §3.10).
 
-    grid = (B,) over slots of a *packed* ragged query block: slot ``b`` owns
-    packed rows ``[q_start[b], q_start[b] + q_len[b])`` (``q_len ≤ chunk_cap``),
-    all three per-slot extents riding as scalar-prefetch vectors alongside the
-    flattened page table. The K/V pools stay in HBM and each slot's live pages
-    stream through the identical double-buffered async-copy pipeline as
-    ``_paged_decode_kernel`` — int8-KV scale tiles included — so warm
-    (radix-hit) suffix prefill, cold prefill, later chunks of the same prompt,
-    and the q_len == 1 decode degenerate share one launch with no bucket
-    padding.
+    grid = (B, row tiles). Slot ``b``'s chunk arrives as its own zero-padded
+    (C·G, Hkv·D) query rows — row ``r`` is chunk token ``r // G``, group
+    ``r % G``, lanes (kv head, d) — and grid step (b, rb) scores rows
+    ``[rb·TR, (rb+1)·TR)`` of it. A tile past the slot's ``q_len·G`` valid
+    rows is dead: it walks no page and writes zeros. A live tile walks only
+    the pages its newest token can see (causal skipping), through the same
+    double-buffered DMA pipeline as ``_paged_decode_kernel`` — int8-KV scale
+    rows included — so warm (radix-hit) suffix prefill, cold prefill, later
+    chunks of the same prompt, and the q_len == 1 decode degenerate share one
+    launch.
 
     The chunk starts at absolute position ``cs = kv_len - q_len`` (cs ==
     prefix_len for the first chunk); chunk token i sits at ``cs + i`` and the
-    causal mask is per score-tile row: ``k_pos <= cs + row // G``. Key
-    positions inside ``[cs, kv_len)`` — the chunk's own tokens, already
-    scattered into the pool before the launch — are *overlaid* with the packed
-    floating-point ``k_new``/``v_new`` rows (and their int8-KV scale columns
-    neutralized to 1.0): the chunk attends itself unquantized, exactly the
-    in-flight fp-suffix overlay of ``layers.paged_prefill_attention``, so
-    chunked numerics match the bucketed warm path. The packed buffers carry
-    ``ps`` leading pad rows so the per-page overlay offset
-    ``q_start + j·ps - cs`` stays in-bounds when a chunk starts mid-page.
-
-    The output block is shared by every grid step (zeroed at b == 0; the TPU
-    grid is sequential, so the read-modify-write blend below is ordered):
-    each slot blends exactly its ``q_len`` valid rows back into
-    ``[q_start, q_start + chunk_cap)`` and rows past ``q_len`` keep their
-    previous contents — packed rows no slot owns stay zero, and a dead slot
-    (q_len == 0) skips its page loop entirely (``n_live = 0``)."""
-    if kv_int8:
-        ks_hbm, vs_hbm, o_ref = refs
-    else:
-        o_ref, = refs
-    b = pl.program_id(0)
-
-    @pl.when(b == 0)
-    def _zero():
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    qs = qstart_ref[b]
+    causal mask is per score-tile row. Key positions inside ``[cs, kv_len)``
+    — the chunk's own tokens, already scattered into the pool before the
+    launch — are *overlaid* with the slot's floating-point ``k_new``/``v_new``
+    rows (and their int8-KV scales neutralized to 1.0): the chunk attends
+    itself unquantized, exactly the in-flight fp-suffix overlay of
+    ``layers.paged_prefill_attention``, so chunked numerics match the
+    bucketed warm path. The per-slot overlay rows start at the chunk's first
+    page boundary, so every per-page overlay slice is page-aligned even when
+    a chunk starts mid-page."""
+    s_hbm, o_ref = (refs[:2], refs[2]) if kv_int8 else (None, refs[0])
+    b, rb = pl.program_id(0), pl.program_id(1)
     qn = qlen_ref[b]
     kvl = kvlen_ref[b]
     cs = kvl - qn                               # chunk's first absolute position
-    C, G = chunk_cap, n_groups
-    R = C * G
-    D = q_ref.shape[-1]
-    P = k_hbm.shape[0]
-    Npad = kn_ref.shape[0]
-    n_live = jnp.where(qn > 0, pl.cdiv(kvl, ps), 0)
-    win_idx = jax.lax.broadcasted_iota(jnp.int32, (R, ps), 0) // G
-    q_pos = cs + jnp.minimum(win_idx, jnp.maximum(qn - 1, 0))
+    TR, G, D = row_tile, n_groups, head_dim
+    n_ov = kn_ref.shape[1]
+    row0 = rb * TR
+    last_tok = jnp.minimum((row0 + TR - 1) // G, qn - 1)
+    n_live = jnp.where(row0 < qn * G, pl.cdiv(cs + last_tok + 1, ps), 0)
+    tok = (row0 + jax.lax.broadcasted_iota(jnp.int32, (TR, ps), 0)) // G
+    q_pos = cs + jnp.minimum(tok, jnp.maximum(qn - 1, 0))
 
-    def body(kbuf, vbuf, sbuf, sem):
-        def dmas(slot, j):
-            # sentinel clamp exactly as _paged_decode_kernel: unreachable below
-            # kv_len for live rows, garbage-but-finite on all-sentinel rows
-            page = jnp.minimum(
-                tab_ref[b * n_pages_max + jnp.minimum(j, n_pages_max - 1)], P - 1)
-            copies = [
-                pltpu.make_async_copy(k_hbm.at[page], kbuf.at[slot],
-                                      sem.at[slot, 0]),
-                pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot],
-                                      sem.at[slot, 1]),
-            ]
-            if kv_int8:
-                copies += [
-                    pltpu.make_async_copy(ks_hbm.at[page], sbuf.at[slot, 0],
-                                          sem.at[slot, 2]),
-                    pltpu.make_async_copy(vs_hbm.at[page], sbuf.at[slot, 1],
-                                          sem.at[slot, 3]),
-                ]
-            return copies
+    def page_step(j, kt, vt, sc, carry):
+        k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (TR, ps), 1)
+        mask = k_pos <= q_pos
+        if window is not None:
+            mask &= (q_pos - k_pos) < window
+        # fp overlay of the chunk's own tokens: in-page rows at absolute
+        # positions >= cs read the slot's fp k_new/v_new rows instead of the
+        # pool (and skip the int8 scales). Overlay row r holds position
+        # ps·(cs // ps) + r, so page j starts at a page-aligned row; the start
+        # clamps so pure-history pages stay in-bounds — their rows all fail
+        # the >= cs test, so the fetched rows never contribute.
+        icd = (jax.lax.broadcasted_iota(jnp.int32, (ps, D), 0) + j * ps) >= cs
+        ic2 = (jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1) + j * ps) >= cs
+        off = pl.multiple_of(
+            jnp.clip(j - cs // ps, 0, n_ov // ps - 1) * ps, ps)
+        ov_k = kn_ref[0, pl.ds(off, ps), :]                   # (ps, Hkv·D)
+        ov_v = vn_ref[0, pl.ds(off, ps), :]
+        out = []
+        for h in range(n_kv_heads):          # static unroll over kv heads
+            lanes, srow = slice(h * D, (h + 1) * D), slice(h * ps, (h + 1) * ps)
+            out.append(_online_update(
+                carry[h], q_ref[0, :, lanes].astype(jnp.float32),
+                jnp.where(icd, ov_k[:, lanes], kt[:, lanes].astype(jnp.float32)),
+                jnp.where(icd, ov_v[:, lanes], vt[:, lanes].astype(jnp.float32)),
+                mask,
+                jnp.where(ic2, 1.0, sc[0, :, srow]) if kv_int8 else None,
+                jnp.where(ic2, 1.0, sc[1, :, srow]) if kv_int8 else None,
+                scale=scale, softcap=softcap))
+        return tuple(out)
 
-        @pl.when(n_live > 0)
-        def _warmup():
-            for c in dmas(0, 0):
-                c.start()
+    def finish(state):
+        o_ref[0] = jnp.concatenate([_normalized(c) for c in state],
+                                   axis=1).astype(o_ref.dtype)
 
-        def page_step(j, carry):
-            slot = jax.lax.rem(j, 2)
-
-            @pl.when(j + 1 < n_live)
-            def _prefetch():
-                for c in dmas(1 - slot, j + 1):
-                    c.start()
-
-            for c in dmas(slot, j):
-                c.wait()
-            k_pos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (R, ps), 1)
-            mask = k_pos <= q_pos
-            if window is not None:
-                mask &= (q_pos - k_pos) < window
-            # fp overlay of the chunk's own tokens: in-page rows at absolute
-            # positions >= cs read the packed fp k_new/v_new instead of the
-            # pool (and skip the int8 scales). The dynamic-slice start clamps
-            # so pure-history pages (offset < 0) stay in-bounds — their rows
-            # all fail the >= cs test, so the fetched bytes never contribute.
-            row_pos = jax.lax.broadcasted_iota(jnp.int32, (ps, D), 0) + j * ps
-            icd = row_pos >= cs                                   # (ps, D)
-            ic2 = (jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-                   + j * ps) >= cs                                # (1, ps)
-            off = jnp.clip(qs + (j * ps - cs), 0, Npad - ps)
-            ov_k = kn_ref[pl.ds(off, ps)]                         # (ps, Hkv, D)
-            ov_v = vn_ref[pl.ds(off, ps)]
-            scales = sbuf[slot] if kv_int8 else None              # (2, Hkv, ps)
-            out = []
-            for h in range(n_kv_heads):        # static unroll over kv heads
-                m_prev, l_prev, acc_prev = carry[h]
-                q = q_ref[h, pl.ds(qs, C)].reshape(R, D).astype(jnp.float32)
-                k = jnp.where(icd, ov_k[:, h, :].astype(jnp.float32),
-                              kbuf[slot, :, h, :].astype(jnp.float32))
-                s = jax.lax.dot_general(
-                    q, k, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                if kv_int8:
-                    s = s * jnp.where(ic2, 1.0, scales[0, h:h + 1])
-                if softcap is not None:
-                    s = softcap * jnp.tanh(s / softcap)
-                m_new = jnp.maximum(
-                    m_prev, jnp.max(jnp.where(mask, s, NEG_INF), axis=1))
-                p = jnp.where(mask, jnp.exp(s - m_new[:, None]), 0.0)
-                corr = jnp.exp(m_prev - m_new)
-                v = jnp.where(icd, ov_v[:, h, :].astype(jnp.float32),
-                              vbuf[slot, :, h, :].astype(jnp.float32))
-                pv = (p * jnp.where(ic2, 1.0, scales[1, h:h + 1])
-                      if kv_int8 else p)
-                out.append((m_new, l_prev * corr + jnp.sum(p, axis=1),
-                            acc_prev * corr[:, None] + jax.lax.dot_general(
-                                pv, v, (((1,), (0,)), ((), ())),
-                                preferred_element_type=jnp.float32)))
-            return tuple(out)
-
-        init = tuple((jnp.full((R,), NEG_INF, jnp.float32),
-                      jnp.zeros((R,), jnp.float32),
-                      jnp.zeros((R, D), jnp.float32))
-                     for _ in range(n_kv_heads))
-        state = jax.lax.fori_loop(0, n_live, page_step, init)
-        tok = jax.lax.broadcasted_iota(jnp.int32, (C, G, D), 0)
-        for h in range(n_kv_heads):
-            _, l, acc = state[h]
-            new = (acc / jnp.maximum(l, 1e-30)[:, None]).astype(o_ref.dtype)
-            old = o_ref[h, pl.ds(qs, C)]                          # (C, G, D)
-            o_ref[h, pl.ds(qs, C)] = jnp.where(tok < qn,
-                                               new.reshape(C, G, D), old)
-
-    pl.run_scoped(
-        body,
-        kbuf=pltpu.VMEM((2,) + k_hbm.shape[1:], k_hbm.dtype),
-        vbuf=pltpu.VMEM((2,) + v_hbm.shape[1:], v_hbm.dtype),
-        sbuf=pltpu.VMEM((2, 2, n_kv_heads, ps), jnp.float32),
-        sem=pltpu.SemaphoreType.DMA((2, 4)),
-    )
+    _walk_pages(tab_ref, b, n_pages_max, n_live, k_hbm, v_hbm, s_hbm,
+                page_step, _init_carry(n_kv_heads, TR, D), finish)
 
 
 def ragged_prefill_attention_pallas(
@@ -504,61 +455,94 @@ def ragged_prefill_attention_pallas(
     window: Optional[int] = None, softcap: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (Hkv, Npad, G, D) packed ragged queries (``ops`` pads ``ps`` leading
-    + ``chunk_cap`` trailing zero rows and adds the leading pad to
-    ``q_start``); k_new/v_new: (Npad, Hkv, D) the chunk tokens' fp K/V in the
-    same packed layout; pools/page_table/scales exactly as
-    :func:`paged_decode_attention_pallas`; q_start/q_len/kv_len: (B,) int32
-    per-slot packed offset, chunk length (≤ chunk_cap; 0 ⇒ dead slot) and
-    total post-scatter visible length → (Hkv, Npad, G, D) with slot b's rows
-    at ``[q_start[b], q_start[b] + q_len[b])`` and every other row zero.
+    """q: (Hkv, N, G, D) packed ragged queries; k_new/v_new: (N, Hkv, D) the
+    chunk tokens' fp K/V in the same packed layout; pools/page_table/scales
+    exactly as :func:`paged_decode_attention_pallas`; q_start/q_len/kv_len:
+    (B,) int32 per-slot packed offset, chunk length (≤ chunk_cap; 0 ⇒ dead
+    slot) and total post-scatter visible length → (Hkv, N, G, D) with slot
+    b's rows at ``[q_start[b], q_start[b] + q_len[b])`` and every other row
+    zero.
 
     One launch serves cold prefill, warm suffix prefill, mid-prompt chunks
     and single-token decode rows (see ``_ragged_prefill_kernel``); the pools
-    never materialize a dense view and dead slots skip their page walk.
+    never materialize a dense view and dead slots skip their page walk. The
+    packed rows are regrouped per slot here (queries lane-dense as
+    (B, C·G, Hkv·D), overlay rows page-aligned as (B, ps·(⌈C/ps⌉ + 1),
+    Hkv·D) f32), so the
+    kernel's blocks are fixed-size tiles the grid pipelines and the VMEM
+    footprint does not grow with N.
     """
-    Hkv, Npad, G, D = q.shape
+    Hkv, N, G, D = q.shape
     P, ps = k_pages.shape[0], k_pages.shape[1]
     B, maxP = page_table.shape
-    assert k_new.shape == v_new.shape == (Npad, Hkv, D), (k_new.shape, q.shape)
+    C = chunk_cap
+    assert k_new.shape == v_new.shape == (N, Hkv, D), (k_new.shape, q.shape)
     assert q_start.shape == q_len.shape == kv_len.shape == (B,)
-    assert chunk_cap >= 1 and Npad >= ps + max(ps, chunk_cap), (Npad, ps, chunk_cap)
+    assert C >= 1, C
     kv_int8 = k_scale is not None
     assert kv_int8 == (v_scale is not None), "pass both scale pools or neither"
 
+    qs = q_start.astype(jnp.int32)
+    rows = C * G
+    TR = min(rows, RAGGED_ROW_TILE)
+    n_tiles = pl.cdiv(rows, TR)
+    # per-slot query rows: (B, C, G, Hkv·D) → (B, C·G, Hkv·D), padded to tiles
+    tok = jnp.clip(qs[:, None] + jnp.arange(C)[None], 0, N - 1)      # (B, C)
+    q_ld = jnp.transpose(q, (1, 2, 0, 3)).reshape(N, G, Hkv * D)[tok]
+    q_ld = jnp.pad(q_ld.reshape(B, rows, Hkv * D),
+                   ((0, 0), (0, n_tiles * TR - rows), (0, 0)))
+    # per-slot fp overlay rows from the chunk's first page boundary: local
+    # row r holds absolute position ps·(cs // ps) + r
+    cs = kv_len.astype(jnp.int32) - q_len.astype(jnp.int32)
+    n_ov = (pl.cdiv(C, ps) + 1) * ps
+    ov = jnp.clip((qs - cs % ps)[:, None] + jnp.arange(n_ov)[None], 0, N - 1)
+    kn = k_new.astype(jnp.float32).reshape(N, Hkv * D)[ov]
+    vn = v_new.astype(jnp.float32).reshape(N, Hkv * D)[ov]
+
     kernel = functools.partial(
         _ragged_prefill_kernel, ps=ps, n_pages_max=maxP, n_kv_heads=Hkv,
-        n_groups=G, chunk_cap=chunk_cap, scale=D ** -0.5, window=window,
+        n_groups=G, head_dim=D, row_tile=TR, scale=D ** -0.5, window=window,
         softcap=softcap, kv_int8=kv_int8)
-    full = lambda shape: pl.BlockSpec(shape, lambda b, *pref: (0,) * len(shape))
+
+    def q_tile(b, rb, tab, qln, kvl):
+        # dead tiles re-point at the last live one: no refetch
+        last = jnp.maximum(pl.cdiv(qln[b] * G, TR) - 1, 0)
+        return (b, jnp.minimum(rb, last), 0)
+
     in_specs = [
-        full((Hkv, Npad, G, D)),                     # packed q, VMEM-resident
-        full((Npad, Hkv, D)),                        # packed fp k_new overlay
-        full((Npad, Hkv, D)),                        # packed fp v_new overlay
-        pl.BlockSpec(memory_space=pltpu.ANY),        # k pool, paged via DMA
-        pl.BlockSpec(memory_space=pltpu.ANY),        # v pool
+        pl.BlockSpec((1, TR, Hkv * D), q_tile),
+        pl.BlockSpec((1, n_ov, Hkv * D), lambda b, rb, *_: (b, 0, 0)),
+        pl.BlockSpec((1, n_ov, Hkv * D), lambda b, rb, *_: (b, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),        # k pool, paged via DMA
+        pl.BlockSpec(memory_space=pl.ANY),        # v pool
     ]
-    args = [q, k_new, v_new, k_pages, v_pages]
+    args = [q_ld, kn, vn, k_pages.reshape(P, ps, Hkv * D),
+            v_pages.reshape(P, ps, Hkv * D)]
     if kv_int8:
         assert k_scale.shape == v_scale.shape == (P, Hkv, ps), (
             k_scale.shape, (P, Hkv, ps))
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY)] * 2
-        args += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(B,),
+        num_scalar_prefetch=3,
+        grid=(B, n_tiles),
         in_specs=in_specs,
-        out_specs=full((Hkv, Npad, G, D)),
+        out_specs=pl.BlockSpec((1, TR, Hkv * D), lambda b, rb, *_: (b, rb, 0)),
     )
     pref = [page_table.reshape(-1).astype(jnp.int32),
-            q_start.astype(jnp.int32), q_len.astype(jnp.int32),
-            kv_len.astype(jnp.int32)]
-    return pl.pallas_call(
+            q_len.astype(jnp.int32), kv_len.astype(jnp.int32)]
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((Hkv, Npad, G, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, n_tiles * TR, Hkv * D), q.dtype),
         interpret=interpret,
     )(*pref, *args)
+    # back to the packed layout: slot b's token i → row q_start[b] + i
+    out = out[:, :rows].reshape(B * C, G, Hkv, D)
+    tgt = jnp.where(jnp.arange(C)[None] < q_len.astype(jnp.int32)[:, None],
+                    qs[:, None] + jnp.arange(C)[None], N).reshape(-1)
+    packed = jnp.zeros((N, G, Hkv, D), q.dtype).at[tgt].set(out, mode="drop")
+    return jnp.transpose(packed, (2, 0, 1, 3))
 
 
 def flash_attention_pallas(

@@ -359,7 +359,7 @@ def _ragged_prefill_attention(q, k_new, v_new, k_pages, v_pages, page_table,
                               v_scale_pages, *, chunk_cap, window, softcap,
                               mesh, exec_mode):
     Nt, H, D = q.shape
-    Hkv, ps = k_pages.shape[2], k_pages.shape[1]
+    Hkv = k_pages.shape[2]
     G = H // Hkv
 
     def body(q, k_new, v_new, k_pages, v_pages, page_table, q_start, q_len,
@@ -377,29 +377,20 @@ def _ragged_prefill_attention(q, k_new, v_new, k_pages, v_pages, page_table,
                 chunk_cap=chunk_cap, k_scale_pages=k_scale_pages,
                 v_scale_pages=v_scale_pages, window=window, softcap=softcap)
             return out.reshape(Nt, H, D)
-        # packed (Nt, H, D) → the kernel's head-major (Hkv, Npad, G, D) with
-        # ps leading pad rows (mid-page chunk-start overlay offsets stay
-        # in-bounds) and max(ps, chunk_cap) trailing pad rows (the per-page
-        # overlay slice is ps rows wide and the blend writes chunk_cap rows —
-        # both must stay in-bounds past the last slot); q_start shifts by the
-        # leading pad
-        trail = max(ps, chunk_cap)
+        # packed (Nt, H, D) → the kernel's head-major (Hkv, Nt, G, D)
         qg = jnp.transpose(q.reshape(Nt, Hkv, G, D), (1, 0, 2, 3))
-        qp = jnp.pad(qg, ((0, 0), (ps, trail), (0, 0), (0, 0)))
-        knp = jnp.pad(k_new, ((ps, trail), (0, 0), (0, 0)))
-        vnp = jnp.pad(v_new, ((ps, trail), (0, 0), (0, 0)))
         ks = vs = None
         if k_scale_pages is not None:
             ks = jnp.transpose(k_scale_pages[..., 0], (0, 2, 1))
             vs = jnp.transpose(v_scale_pages[..., 0], (0, 2, 1))
         out = _fa.ragged_prefill_attention_pallas(
-            qp, knp, vnp, k_pages, v_pages, page_table,
-            jnp.reshape(q_start, (-1,)).astype(jnp.int32) + ps,
+            qg, k_new, v_new, k_pages, v_pages, page_table,
+            jnp.reshape(q_start, (-1,)).astype(jnp.int32),
             jnp.broadcast_to(jnp.reshape(q_len, (-1,)).astype(jnp.int32), (B,)),
             jnp.broadcast_to(jnp.reshape(kv_len, (-1,)).astype(jnp.int32), (B,)),
             chunk_cap=chunk_cap, k_scale=ks, v_scale=vs,
             window=window, softcap=softcap, interpret=_interpret())
-        return jnp.transpose(out[:, ps:ps + Nt], (1, 0, 2, 3)).reshape(Nt, H, D)
+        return jnp.transpose(out, (1, 0, 2, 3)).reshape(Nt, H, D)
 
     return hints.manual_kernel(
         body, (q, k_new, v_new, k_pages, v_pages, page_table, q_start, q_len,

@@ -27,12 +27,14 @@ import os
 from typing import Dict, List, Optional
 
 from repro.configs import SHAPES, get
+from repro.launch.peaks import V5E, peaks
 
-# TPU v5e hardware constants (per chip).
-PEAK_BF16 = 197e12          # FLOP/s
-PEAK_INT8 = 394e12          # OP/s (MXU int8 runs at 2x bf16)
-HBM_BW = 819e9              # B/s
-ICI_BW = 50e9               # B/s per link
+# Projections target one TPU v5e chip; its peaks come from the one table.
+_CHIP = peaks(V5E)
+PEAK_BF16 = _CHIP.bf16_flops        # FLOP/s
+PEAK_INT8 = _CHIP.int8_ops          # OP/s (MXU int8 runs at 2x bf16)
+HBM_BW = _CHIP.hbm_bw               # B/s
+ICI_BW = _CHIP.ici_bw / 4           # B/s per link (4 links per v5e chip)
 
 
 MESH_DEVICES = {"pod16x16": 256, "pod2x16x16": 512}

@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get
 from repro.core import calibration, qlinear as ql
 from repro.data import make_train_batches
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.models.layers import QuantContext
 from repro.models.quantize import quantize_tree, quantized_bytes
@@ -67,11 +68,12 @@ def main() -> None:
                          "default single-device path)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = get(args.arch, smoke=args.smoke)
     quant = QUANTS[args.quant]
     key = jax.random.PRNGKey(args.seed)
-    params = M.init_params(key, cfg)
+    params = M.init_params_on_device(key, cfg)
     base_bytes = quantized_bytes(params)
 
     if args.quant == "int8":

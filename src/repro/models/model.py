@@ -113,6 +113,16 @@ def init_params(key, cfg: ModelConfig, dtype=None) -> dict:
     return params
 
 
+def init_params_on_device(key, cfg: ModelConfig, dtype=None) -> dict:
+    """:func:`init_params` as one compiled program on the default device, in
+    ``dtype`` (default ``cfg.dtype``). Each leaf is drawn and cast inside the
+    program, so no float32 tree is ever materialized: at full width that tree
+    alone would be 4 bytes per parameter (12.3 GiB for granite-moe-3b-a800m)
+    on a 16 GiB chip."""
+    dtype = jnp.dtype(dtype or cfg.dtype)
+    return jax.jit(init_params, static_argnums=(1, 2))(key, cfg, dtype)
+
+
 # ======================================================================================
 # Sublayer application
 # ======================================================================================

@@ -28,6 +28,13 @@ def _pathstr(path) -> str:
     return "/".join(out)
 
 
+# One compiled program per weight shape: the b-fold, scale and round fuse into
+# passes over the weight, where op-by-op execution would hold several float32
+# copies of a whole expert stack (4 GiB each at granite-moe-3b-a800m width).
+_prepare_int8 = jax.jit(ql.prepare_int8, static_argnums=1)
+_prepare_int4 = jax.jit(ql.prepare_int4, static_argnums=1)
+
+
 def quantize_tree(params, cfg: ql.QuantConfig,
                   tables: Optional[Dict[str, np.ndarray]] = None):
     """Returns a new params pytree with prepared quantized linears.
@@ -45,8 +52,8 @@ def quantize_tree(params, cfg: ql.QuantConfig,
                     if cmax is None and prefix in tables:
                         cmax = jnp.asarray(tables[prefix])
                     if cfg.w_bits <= 4:
-                        return ql.prepare_int4({"w": w}, cfg, cmax)
-                    return ql.prepare_int8({"w": w}, cfg, cmax)
+                        return _prepare_int4({"w": w}, cfg, cmax)
+                    return _prepare_int8({"w": w}, cfg, cmax)
             return {k: convert(v, f"{prefix}/{k}" if prefix else k)
                     for k, v in node.items()}
         if isinstance(node, list):

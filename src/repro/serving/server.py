@@ -194,6 +194,7 @@ class _Replica:
         self.tracker = RestartTracker(max_restarts=server.max_restarts)
         self.total_steps = 0                       # survives restarts
         self.engine: Optional[ServeEngine] = None
+        self.build_error: Optional[BaseException] = None
         self.thread: Optional[threading.Thread] = None
 
     # ------------------------------------------------------------ loop-side
@@ -223,19 +224,34 @@ class _Replica:
     def _main(self) -> None:
         ctx = (jax.default_device(self.device) if self.device is not None
                else contextlib.nullcontext())
-        try:
-            with ctx:
-                engine = ServeEngine(self.server.cfg, self.server.params,
+        with ctx:
+            try:
+                # a pinned replica commits its params to its own device (the
+                # caches the engine makes land there under default_device),
+                # so its steps run there and not where the caller's arrays live
+                params = (self.server.params if self.device is None
+                          else jax.device_put(self.server.params, self.device))
+                engine = ServeEngine(self.server.cfg, params,
                                      config=self.server.config,
                                      quant=self.server.quant)
-                engine.on_token = self._on_token
-                self.engine = engine
-                self.health.state = "live"
+            except Exception as e:
+                # a replica that cannot be built will not build on a restart
+                # either: it is dead, and the error surfaces (start() raises)
+                # instead of turning into failover
+                self.build_error = e
+                self.health.state = "dead"
+                self.health.last_error = f"{type(e).__name__}: {e}"
+                log.error("replica %d failed to build: %s", self.idx, e)
                 self.ready.set()
-                self._loop(engine)
-        except Exception as e:      # WorkerFailure or anything else: drain
+                return
+            engine.on_token = self._on_token
+            self.engine = engine
+            self.health.state = "live"
             self.ready.set()
-            self._fail(e)
+            try:
+                self._loop(engine)
+            except Exception as e:  # WorkerFailure or anything else: drain
+                self._fail(e)
 
     def _loop(self, engine: ServeEngine) -> None:
         finished: List[EngineRequest] = []
@@ -347,7 +363,10 @@ class AsyncServer:
     of grace before an :class:`AdmissionError`; ``injectors`` maps replica
     index → :class:`FailureInjector` for fault-injection tests;
     ``devices="auto"`` pins replica *i* to ``jax.devices()[i]`` when the
-    process has at least ``replicas`` devices (single-device hosts share).
+    process has at least ``replicas`` devices (single-device hosts share): a
+    pinned replica commits its params and caches to its device. A replica
+    whose engine fails to build makes :meth:`start` raise; failover covers
+    failures while serving.
     ``kernel_stats=True`` replays each finished request eagerly to report the
     paper's §4.1 kernel proportion in its metrics. Use as an async context
     manager, or call :meth:`start` / :meth:`aclose` explicitly.
@@ -400,6 +419,11 @@ class AsyncServer:
             r.start()
         for r in self.replicas:
             await self._loop.run_in_executor(None, r.ready.wait)
+        failed = [r for r in self.replicas if r.build_error is not None]
+        if failed:
+            await self.aclose()
+            raise RuntimeError(
+                f"replica {failed[0].idx} failed to build") from failed[0].build_error
         self._started = True
         return self
 

@@ -340,8 +340,6 @@ def manual_kernel(fn, args: tuple, mesh=None):
     ``fn`` where the partitioner cannot touch it)."""
     if mesh is None:
         return fn(*args)
-    from jax.experimental.shard_map import shard_map
-
     replicated = jax.tree_util.tree_map(lambda _: P(), args)
-    return shard_map(fn, mesh=mesh, in_specs=replicated, out_specs=P(),
-                     check_rep=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=replicated, out_specs=P(),
+                         check_vma=False)(*args)

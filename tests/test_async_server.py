@@ -18,6 +18,7 @@ from repro.models.quantize import quantize_tree
 from repro.runtime import FailureInjector
 from repro.serving import engine as E
 from repro.serving.api import AdmissionError, FinishReason, Request
+from repro.serving import server as server_mod
 from repro.serving.config import EngineConfig
 from repro.serving.server import AsyncServer
 
@@ -344,5 +345,44 @@ class TestReplicaFailure:
                 _, fin = await _collect(srv, Request(
                     prompt=prompts[0].tolist(), max_new=3, replica_hint=0))
                 assert fin.metrics.replica == 1   # hint ignored: replica dead
+
+        asyncio.run(main())
+
+
+class TestReplicaBuild:
+    def test_build_failure_raises_instead_of_failover(self, small, monkeypatch):
+        """An engine that cannot be built will not build on a restart either:
+        start() raises with the engine's error as the cause, and nothing is
+        restarted or requeued."""
+        cfg, params, _ = small
+
+        def broken(*args, **kwargs):
+            raise ValueError("engine cannot be built")
+
+        monkeypatch.setattr(server_mod, "ServeEngine", broken)
+
+        async def main():
+            srv = AsyncServer(cfg, params, replicas=2,
+                              config=EngineConfig(batch_size=2, max_len=T))
+            with pytest.raises(RuntimeError, match="failed to build") as err:
+                await srv.start()
+            assert isinstance(err.value.__cause__, ValueError)
+            assert srv.counters["restarts"] == 0
+            assert all(r.health.state == "dead" for r in srv.replicas)
+
+        asyncio.run(main())
+
+    def test_pinned_replica_holds_params_and_caches_on_its_device(self, small):
+        cfg, params, _ = small
+        dev = jax.devices()[-1]
+
+        async def main():
+            async with AsyncServer(cfg, params, replicas=1, devices=[dev],
+                                   config=EngineConfig(batch_size=2,
+                                                       max_len=T)) as srv:
+                eng = srv.replicas[0].engine
+                for tree in (eng.params, eng.caches):
+                    assert {d for x in jax.tree_util.tree_leaves(tree)
+                            for d in x.devices()} == {dev}
 
         asyncio.run(main())

@@ -182,3 +182,17 @@ class TestCellSupport:
         assert cell_supported(get("mamba2-130m"), SHAPES["long_500k"])[0]
         assert cell_supported(get("zamba2-1.2b"), SHAPES["long_500k"])[0]
         assert not cell_supported(get("deepseek-coder-33b"), SHAPES["long_500k"])[0]
+
+
+def test_init_params_on_device_is_the_cast_eager_init():
+    """The compiled init draws exactly the eager init's values, cast to
+    cfg.dtype — without a float32 tree in between."""
+    cfg = get("granite-moe-3b-a800m", smoke=True)
+    key = jax.random.PRNGKey(3)
+    got = M.init_params_on_device(key, cfg)
+    want = M.init_params(key, cfg, jnp.dtype(cfg.dtype))
+    assert jax.tree_util.tree_structure(got) == jax.tree_util.tree_structure(want)
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
