@@ -309,7 +309,7 @@ def _spec_lines(cfg, variants, n_req: int, steps):
             for mode, eng in engs.items():
                 lines.append(
                     f"serving_bench_spec,{tag},{mode},{best[mode]:.1f},"
-                    f"{eng.accept_rate():.3f},{eng.tokens_per_step():.2f}")
+                    f"{eng.stats().accept_rate:.3f},{eng.stats().tokens_per_step:.2f}")
     finally:
         if prev is not None:
             os.environ["REPRO_KERNEL_EXEC"] = prev
@@ -420,7 +420,7 @@ def _prefix_lines(cfg, variants, n_req: int, steps):
             peak = eng.counters["peak_pages_in_use"] or dense_pages
             lines.append(
                 f"serving_bench_prefix,{tag},{layout},{best[layout]:.1f},"
-                f"{eng.prefix_hit_rate():.3f},{eng.counters['prefill_tokens']},"
+                f"{eng.stats().prefix_hit_rate:.3f},{eng.counters['prefill_tokens']},"
                 f"{saved},{peak},{dense_pages / peak:.2f}")
     return lines
 
@@ -572,7 +572,7 @@ def _zoo_lines(quick: bool, steps):
             best[scheduler] = max(best[scheduler], tok_s)
     for scheduler, eng in engs.items():
         lines.append(f"serving_bench_zoo,mamba2,{scheduler},"
-                     f"{best[scheduler]:.1f},{eng.occupancy():.2f},"
+                     f"{best[scheduler]:.1f},{eng.stats().occupancy:.2f},"
                      f"{eng.counters['mid_decode_admissions']}")
 
     if quick:
@@ -597,7 +597,7 @@ def _zoo_lines(quick: bool, steps):
             tok_s, eng = one_pass()
             best_m = max(best_m, tok_s)
         lines.append(f"serving_bench_zoo,granite-moe{mesh_tag},continuous,"
-                     f"{best_m:.1f},{eng.occupancy():.2f},"
+                     f"{best_m:.1f},{eng.stats().occupancy:.2f},"
                      f"{eng.counters['mid_decode_admissions']}")
     return lines
 
@@ -676,7 +676,7 @@ def _run(quick: bool = False):
                     best[scheduler] = max(best[scheduler], tok_s)
             for scheduler, eng in engs.items():
                 lines.append(f"serving_bench,{tag}{mesh_tag},{scheduler},"
-                             f"{best[scheduler]:.1f},{eng.occupancy():.2f},"
+                             f"{best[scheduler]:.1f},{eng.stats().occupancy:.2f},"
                              f"{eng.counters['mid_decode_admissions']}")
 
     # shared-system-prompt workload: dense vs paged prefix reuse (§3.8);

@@ -117,7 +117,7 @@ def main() -> None:
     n_tok = sum(len(r.out) for r in done)
     print(f"served {len(done)} requests, {n_tok} tokens in {dt:.2f}s "
           f"({n_tok / dt:.1f} tok/s) quant={quant.tag()} "
-          f"scheduler={args.scheduler} occupancy={engine.occupancy():.2f}")
+          f"scheduler={args.scheduler} occupancy={engine.stats().occupancy:.2f}")
     for r in done[:4]:
         print(f"  req {r.rid}: prompt[:4]={r.prompt[:4].tolist()} -> out={r.out[:8]}")
 

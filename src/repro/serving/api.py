@@ -70,9 +70,10 @@ class Request:
 class RequestMetrics:
     """Per-request serving metrics, attached to the final ``finished`` event.
 
-    ``ttft_s`` counts from admission to first token, ``tpot_s`` is the mean
-    inter-token gap after the first, ``queue_wait_s`` is time spent in the
-    admission queue before a replica picked the request up. ``prefix_reused``
+    ``ttft_s`` counts from the server's ``submit`` entry, before any
+    admission wait, to the first token; ``tpot_s`` is the mean inter-token gap
+    after the first; ``queue_wait_s`` is the time from that entry until a
+    replica picked the request up. ``prefix_reused``
     is the §3.8 radix hit length (prompt tokens served from cache), and
     ``kernel_proportion`` is the paper's §4.1 quantization-kernel proportion
     |S⊥|/|S| measured over this request's served activations (``None`` unless

@@ -15,9 +15,9 @@ genuinely cannot work on recurrent state are rejected — each with its own
 :class:`UnsupportedModelError` subclass so callers (and the async server's
 error mapping) can branch on the reason instead of parsing messages.
 
-``EngineStats`` unifies the engine's scattered stats accessors (``occupancy()``,
-``prefix_hit_rate()``, ``accept_rate()``, ``tokens_per_step()``) behind one
-``ServeEngine.stats()`` call with a stable ``to_dict()`` schema shared by
+``EngineStats`` is the one way to read the engine's statistics: slot
+occupancy, prefix hit rate, draft acceptance and tokens per speculative step,
+from ``ServeEngine.stats()``, with a stable ``to_dict()`` schema shared by
 ``benchmarks/serving_bench.py`` and the async server's metrics endpoint.
 """
 from __future__ import annotations
@@ -318,9 +318,8 @@ def config_from_args(args: argparse.Namespace,
 class EngineStats:
     """One snapshot of a ``ServeEngine``'s derived rates + raw counters.
 
-    The derived fields are exactly what the legacy accessors returned
-    (``occupancy()`` etc., now thin delegates); ``counters`` is a copy of the
-    engine's raw counter dict. ``to_dict()`` flattens both into the stable
+    The derived fields are rates over the engine's raw counters; ``counters``
+    is a copy of that dict. ``to_dict()`` flattens both into the stable
     schema ``serving_bench`` rows and the async server's ``metrics()``
     endpoint share — derived rates first, counters after, all floats/ints.
     """

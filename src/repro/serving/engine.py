@@ -35,6 +35,7 @@ LRU-unreferenced cached prefixes evict under pool pressure.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import warnings
 from typing import Callable, List, Optional, Sequence, Union
@@ -49,7 +50,7 @@ from repro.configs.base import ModelConfig
 from repro.core import qlinear as ql
 from repro.models import model as M, state as state_lib
 from repro.models.layers import QuantContext
-from repro.serving import drafter, paging
+from repro.serving import drafter, paging, spans
 from repro.serving.api import FinishReason
 from repro.serving.config import SERVE_PATHS, EngineConfig, EngineStats
 from repro.sharding import hints, planner
@@ -375,6 +376,7 @@ class Request:
     done: bool = False
     finish_reason: Optional[FinishReason] = None   # set at retirement
     prefix_reused: int = 0        # §3.8 radix hit length (prompt tokens)
+    tag: Optional[str] = None     # the caller's id, for span metadata
 
 
 def default_buckets(max_len: int, lo: int = 8) -> List[int]:
@@ -420,8 +422,9 @@ class ServeEngine:
     and greedy acceptance keeps every accepted token equal to what plain
     decode would have sampled — output is **token-exact** vs ``speculate=1``
     (tests/test_speculative.py). Requires greedy sampling, the continuous
-    scheduler and attention-only caches; ``accept_rate()`` /
-    ``tokens_per_step()`` report what the workload's repetitiveness bought.
+    scheduler and attention-only caches; ``stats().accept_rate`` /
+    ``stats().tokens_per_step`` report what the workload's repetitiveness
+    bought.
 
     ``cache_dtype`` sets the fp KV-cache dtype, defaulting to the params dtype
     (a bf16 model serves a bf16 cache); ``kv_cache="int8"`` is unaffected.
@@ -660,6 +663,7 @@ class ServeEngine:
         self._key = jax.random.PRNGKey(seed)
         self._greedy = temperature <= 0.0
         self._step = 0
+        self._n_steps = 0
         self._next_rid = 0
         #: optional per-token hook, called as ``on_token(request, token)``
         #: after every emitted token (the request is already retired when
@@ -683,7 +687,10 @@ class ServeEngine:
             "spec_accepted": 0, "spec_emitted": 0,
             # chunked serving (DESIGN.md §3.10); zero if chunked=False
             "chunk_steps": 0, "chunk_prefill_rows": 0,
-            "chunk_decode_rows": 0}
+            "chunk_decode_rows": 0,
+            # host time, perf_counter nanoseconds: inside step(), blocked on
+            # sampled tokens (engine.sync), inside radix eviction
+            "step_ns": 0, "sync_ns": 0, "evict_ns": 0}
 
     # ---------------------------------------------------------------- submission
 
@@ -710,32 +717,30 @@ class ServeEngine:
         return self.T
 
     def stats(self) -> EngineStats:
-        """Unified statistics snapshot (DESIGN.md §3.11): the derived rates the
-        four legacy accessors returned plus a copy of the raw counters, with a
-        stable ``to_dict()`` schema shared by ``benchmarks/serving_bench.py``
-        and the async server's metrics endpoint."""
+        """Statistics snapshot (DESIGN.md §3.11): slot occupancy, prefix hit
+        rate, draft acceptance and tokens per speculative step, derived from a
+        copy of the raw counters, with a stable ``to_dict()`` schema shared by
+        ``benchmarks/serving_bench.py`` and the async server's metrics
+        endpoint."""
         return EngineStats.from_counters(self.counters, self.B)
 
-    def occupancy(self) -> float:
-        return self.stats().occupancy
+    def _read(self, *xs):
+        """Blocking device-to-host read of a step's sampled tokens."""
+        with spans.span("engine.sync", self.counters, "sync_ns"):
+            out = [np.asarray(x) for x in xs]
+        return out[0] if len(out) == 1 else out
 
-    def prefix_hit_rate(self) -> float:
-        """Fraction of submitted prompt tokens served from shared prefix pages
-        instead of being re-prefilled (paged layout; 0.0 on dense)."""
-        return self.stats().prefix_hit_rate
-
-    def accept_rate(self) -> float:
-        """Fraction of *drafted* tokens the verify step accepted (DESIGN.md
-        §3.9; the mandatory pending token does not count). 0.0 when nothing
-        was drafted (speculate == 1, or the drafter never proposed)."""
-        return self.stats().accept_rate
-
-    def tokens_per_step(self) -> float:
-        """Mean emitted tokens per slot per speculative verify step (≥ 1.0 —
-        plain decode emits exactly 1 per slot-step, so this is the per-request
-        step-count compression speculation bought). 0.0 before any speculative
-        step ran."""
-        return self.stats().tokens_per_step
+    @contextlib.contextmanager
+    def _admit_span(self):
+        """``engine.admit`` around one admission phase; ``rids`` lists the
+        requests it took off the queue (their ``tag``, else their rid)."""
+        with spans.span("engine.admit") as sp:
+            queued = list(self.queue) if sp.is_enabled() else None
+            yield
+            if queued is not None:
+                left = {id(r) for r in self.queue}
+                sp.set_metadata(rids=" ".join(
+                    str(r.tag or r.rid) for r in queued if id(r) not in left))
 
     def _next_key(self) -> jax.Array:
         if self._greedy:            # sampler ignores the key: skip the fold_in op
@@ -841,7 +846,11 @@ class ServeEngine:
         own_n = need - len(shared) + (1 if self.has_state else 0)
         own = self.pool.alloc(own_n)
         if own is None and self.radix is not None:
-            self.counters["pages_evicted"] += self.radix.evict(self.pool, own_n)
+            with spans.span("radix.evict", self.counters, "evict_ns",
+                            want=own_n) as sp:
+                freed = self.radix.evict(self.pool, own_n)
+                sp.set_metadata(freed=freed)
+            self.counters["pages_evicted"] += freed
             own = self.pool.alloc(own_n)
         if cow_src is not None:                # copy is issued before any write
             self.pool.decref([cow_src])
@@ -901,9 +910,10 @@ class ServeEngine:
             row_tables[j, : len(plan["pages"])] = plan["pages"]
             if plan["cow"] is not None:
                 src, dst, ncopy = plan["cow"]
-                self.caches = self._copy_step(
-                    self.caches, jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32), jnp.asarray(ncopy, jnp.int32))
+                with spans.span("engine.launch"):
+                    self.caches = self._copy_step(
+                        self.caches, jnp.asarray(src, jnp.int32),
+                        jnp.asarray(dst, jnp.int32), jnp.asarray(ncopy, jnp.int32))
                 self.counters["cow_copies"] += 1
             self._slots[slot] = r
             # the slot's reference list covers both page kinds: retirement
@@ -925,11 +935,12 @@ class ServeEngine:
             self.counters["prefix_hits"] += 1 if plan["prefix"] > 0 else 0
         self._table_dirty = True
         step = self._admit_warm if warm else self._admit_cold
-        tok, self.caches = step(
-            self.params, jnp.asarray(tokens), jnp.asarray(lens),
-            jnp.asarray(prefixes), jnp.asarray(row_tables),
-            jnp.asarray(row_states), self.caches, self._next_key())
-        tok = np.asarray(tok)
+        with spans.span("engine.launch"):
+            tok, self.caches = step(
+                self.params, jnp.asarray(tokens), jnp.asarray(lens),
+                jnp.asarray(prefixes), jnp.asarray(row_tables),
+                jnp.asarray(row_states), self.caches, self._next_key())
+        tok = self._read(tok)
         self.counters["prefill_calls"] += 1
         if mid_decode:
             self.counters["mid_decode_admissions"] += 1
@@ -962,10 +973,11 @@ class ServeEngine:
             self._slots[slot] = r
             self.counters["prompt_tokens"] += len(r.prompt)
             self.counters["prefill_tokens"] += len(r.prompt)
-        tok, self.caches = self._admit_step(
-            self.params, jnp.asarray(tokens), jnp.asarray(lens),
-            jnp.asarray(slot_ids), self.caches, self._next_key())
-        tok = np.asarray(tok)
+        with spans.span("engine.launch"):
+            tok, self.caches = self._admit_step(
+                self.params, jnp.asarray(tokens), jnp.asarray(lens),
+                jnp.asarray(slot_ids), self.caches, self._next_key())
+        tok = self._read(tok)
         self.counters["prefill_calls"] += 1
         if mid_decode:
             self.counters["mid_decode_admissions"] += 1
@@ -975,46 +987,49 @@ class ServeEngine:
         return len(batch)
 
     def _admit(self, finished: List[Request]) -> None:
-        while self.queue:
-            free = [i for i, s in enumerate(self._slots) if s is None]
-            if not free:
-                return
-            if self.scheduler == "grouped":
-                # Legacy batcher: whole-batch groups of one exact length, drained to
-                # completion before the next group starts.
-                if len(free) < self.B:
+        if not self.queue or all(s is not None for s in self._slots):
+            return
+        with self._admit_span():
+            while self.queue:
+                free = [i for i, s in enumerate(self._slots) if s is None]
+                if not free:
                     return
-                bucket = len(self.queue[0].prompt)
-                batch, rest = [], []
-                for r in self.queue:
-                    (batch if len(batch) < len(free)
-                     and len(r.prompt) == bucket else rest).append(r)
-                self.queue = rest
-                self._admit_dense_batch(batch, bucket, free, finished)
-                return
-            # Continuous: pick the *largest admittable same-bucket group* over
-            # the whole queue, not queue[0]'s bucket — one odd-length
-            # head-of-line request must not split the majority bucket behind it
-            # into extra (smaller) prefill calls. Ties go to the bucket whose
-            # first request arrived earliest (FIFO fairness); the loop keeps
-            # admitting remaining buckets while slots stay free.
-            groups: dict = {}
-            first: dict = {}
-            for i, r in enumerate(self.queue):
-                b = self._bucket(self._suffix_estimate(r))
-                groups.setdefault(b, []).append(r)
-                first.setdefault(b, i)
-            bucket = max(groups,
-                         key=lambda b: (min(len(groups[b]), len(free)), -first[b]))
-            batch = groups[bucket][: len(free)]
-            taken = {id(r) for r in batch}
-            self.queue = [r for r in self.queue if id(r) not in taken]
-            if self.paged:
-                admitted = self._admit_paged_batch(batch, bucket, free, finished)
-            else:
-                admitted = self._admit_dense_batch(batch, bucket, free, finished)
-            if admitted == 0:
-                return                     # pool exhausted: wait for retirements
+                if self.scheduler == "grouped":
+                    # Legacy batcher: whole-batch groups of one exact length, drained to
+                    # completion before the next group starts.
+                    if len(free) < self.B:
+                        return
+                    bucket = len(self.queue[0].prompt)
+                    batch, rest = [], []
+                    for r in self.queue:
+                        (batch if len(batch) < len(free)
+                         and len(r.prompt) == bucket else rest).append(r)
+                    self.queue = rest
+                    self._admit_dense_batch(batch, bucket, free, finished)
+                    return
+                # Continuous: pick the *largest admittable same-bucket group* over
+                # the whole queue, not queue[0]'s bucket — one odd-length
+                # head-of-line request must not split the majority bucket behind it
+                # into extra (smaller) prefill calls. Ties go to the bucket whose
+                # first request arrived earliest (FIFO fairness); the loop keeps
+                # admitting remaining buckets while slots stay free.
+                groups: dict = {}
+                first: dict = {}
+                for i, r in enumerate(self.queue):
+                    b = self._bucket(self._suffix_estimate(r))
+                    groups.setdefault(b, []).append(r)
+                    first.setdefault(b, i)
+                bucket = max(groups,
+                             key=lambda b: (min(len(groups[b]), len(free)), -first[b]))
+                batch = groups[bucket][: len(free)]
+                taken = {id(r) for r in batch}
+                self.queue = [r for r in self.queue if id(r) not in taken]
+                if self.paged:
+                    admitted = self._admit_paged_batch(batch, bucket, free, finished)
+                else:
+                    admitted = self._admit_dense_batch(batch, bucket, free, finished)
+                if admitted == 0:
+                    return                     # pool exhausted: wait for retirements
 
     # ---------------------------------------------------------------- main loop
 
@@ -1026,17 +1041,18 @@ class ServeEngine:
         may have already handed to another sequence or the prefix index. The
         same applies to the (B,) state table of checkpoint-paged families
         (§3.13); a hybrid engine pushes both."""
-        out = dict(self.caches)
-        if self.has_kv:
-            table = jnp.asarray(self._table)
-            if self.mesh is not None:
-                table = jax.device_put(table, self._repl_sh)
-            out["page_table"] = table
-        if self.has_state:
-            stable = jnp.asarray(self._state_table)
-            if self.mesh is not None:
-                stable = jax.device_put(stable, self._repl_sh)
-            out["state_table"] = stable
+        with spans.span("engine.push_table"):
+            out = dict(self.caches)
+            if self.has_kv:
+                table = jnp.asarray(self._table)
+                if self.mesh is not None:
+                    table = jax.device_put(table, self._repl_sh)
+                out["page_table"] = table
+            if self.has_state:
+                stable = jnp.asarray(self._state_table)
+                if self.mesh is not None:
+                    stable = jax.device_put(stable, self._repl_sh)
+                out["state_table"] = stable
         self.caches = out
         self._table_dirty = False
 
@@ -1081,39 +1097,41 @@ class ServeEngine:
                 d = self.drafter.draft(hist, n_d)
                 wl[i] = 1 + len(d)
                 toks[i, 1:1 + len(d)] = d
-        cur = jnp.asarray(self._pos + wl, jnp.int32)   # post-scatter totals
-        out, self.caches = self._verify_step(
-            self.params, jnp.asarray(toks), self.caches, cur,
-            jnp.asarray(wl), self._next_key())
-        out = np.asarray(out)                          # (B, W) greedy samples
+        with spans.span("engine.launch"):
+            cur = jnp.asarray(self._pos + wl, jnp.int32)   # post-scatter totals
+            out, self.caches = self._verify_step(
+                self.params, jnp.asarray(toks), self.caches, cur,
+                jnp.asarray(wl), self._next_key())
+        out = self._read(out)                          # (B, W) greedy samples
         self.counters["decode_steps"] += 1
         self.counters["spec_steps"] += 1
         self.counters["spec_slot_steps"] += len(active)
         self.counters["active_slot_steps"] += len(active)
-        for i in active:
-            n = 1                                      # pending always lands
-            while n < wl[i] and toks[i, n] == out[i, n - 1]:
-                n += 1
-            self.counters["spec_drafted"] += int(wl[i]) - 1
-            self.counters["spec_accepted"] += n - 1
-            r = self._slots[i]
-            for j in range(n):
-                # advance per emitted token: retire conditions (max_new, EOS,
-                # cache-full) must fire at exactly the same token as a
-                # sequential non-speculative decode would
-                self._pos[i] += 1
-                self._emit(i, int(out[i, j]), finished)
-                self.counters["spec_emitted"] += 1
-                if self._slots[i] is not r:
-                    # retired mid-window: the unemitted tail (and the
-                    # rejected scattered tokens) must be unreachable — the
-                    # retire path has to sentinel the slot's table row and
-                    # drop its page refs before any later scatter/attend
-                    if self.paged:
-                        assert (not self._seq_pages[i]
-                                and (self._table[i] == self.n_pages).all()), \
-                            "mid-window retirement left stale page mappings"
-                    break
+        with spans.span("engine.emit"):
+            for i in active:
+                n = 1                                      # pending always lands
+                while n < wl[i] and toks[i, n] == out[i, n - 1]:
+                    n += 1
+                self.counters["spec_drafted"] += int(wl[i]) - 1
+                self.counters["spec_accepted"] += n - 1
+                r = self._slots[i]
+                for j in range(n):
+                    # advance per emitted token: retire conditions (max_new,
+                    # EOS, cache-full) must fire at exactly the same token as
+                    # a sequential non-speculative decode would
+                    self._pos[i] += 1
+                    self._emit(i, int(out[i, j]), finished)
+                    self.counters["spec_emitted"] += 1
+                    if self._slots[i] is not r:
+                        # retired mid-window: the unemitted tail (and the
+                        # rejected scattered tokens) must be unreachable — the
+                        # retire path has to sentinel the slot's table row and
+                        # drop its page refs before any later scatter/attend
+                        if self.paged:
+                            assert (not self._seq_pages[i]
+                                    and (self._table[i] == self.n_pages).all()), \
+                                "mid-window retirement left stale page mappings"
+                        break
 
     # ------------------------------------------------------------ chunked mode
 
@@ -1124,35 +1142,40 @@ class ServeEngine:
         and its prompt is served chunk-by-chunk out of each step's leftover
         token budget. Radix insertion waits for the final chunk (pages carry
         content only once scattered)."""
-        while self.queue:
-            free = [i for i, s in enumerate(self._slots) if s is None]
-            if not free:
-                return
-            r = self.queue[0]
-            plan = self._plan_paged(r)
-            if plan is None:
-                return                     # pool pressure: wait for retirements
-            self.queue.pop(0)
-            slot = free[0]
-            if plan["cow"] is not None:
-                src, dst, ncopy = plan["cow"]
-                self.caches = self._copy_step(
-                    self.caches, jnp.asarray(src, jnp.int32),
-                    jnp.asarray(dst, jnp.int32), jnp.asarray(ncopy, jnp.int32))
-                self.counters["cow_copies"] += 1
-            self._slots[slot] = r
-            self._seq_pages[slot] = plan["pages"]
-            self._table[slot, :] = self.n_pages
-            self._table[slot, : len(plan["pages"])] = plan["pages"]
-            self._table_dirty = True
-            self._prefill_off[slot] = plan["prefix"]
-            self._prefill_target[slot] = len(r.prompt)
-            r.prefix_reused = plan["prefix"]
-            self.counters["prompt_tokens"] += len(r.prompt)
-            self.counters["prefill_tokens"] += plan["suffix"]
-            self.counters["prefix_tokens_reused"] += plan["prefix"]
-            self.counters["prefix_hits"] += 1 if plan["prefix"] > 0 else 0
-            self._note_pool()
+        if not self.queue or all(s is not None for s in self._slots):
+            return
+        with self._admit_span():
+            while self.queue:
+                free = [i for i, s in enumerate(self._slots) if s is None]
+                if not free:
+                    return
+                r = self.queue[0]
+                plan = self._plan_paged(r)
+                if plan is None:
+                    return                 # pool pressure: wait for retirements
+                self.queue.pop(0)
+                slot = free[0]
+                if plan["cow"] is not None:
+                    src, dst, ncopy = plan["cow"]
+                    with spans.span("engine.launch"):
+                        self.caches = self._copy_step(
+                            self.caches, jnp.asarray(src, jnp.int32),
+                            jnp.asarray(dst, jnp.int32),
+                            jnp.asarray(ncopy, jnp.int32))
+                    self.counters["cow_copies"] += 1
+                self._slots[slot] = r
+                self._seq_pages[slot] = plan["pages"]
+                self._table[slot, :] = self.n_pages
+                self._table[slot, : len(plan["pages"])] = plan["pages"]
+                self._table_dirty = True
+                self._prefill_off[slot] = plan["prefix"]
+                self._prefill_target[slot] = len(r.prompt)
+                r.prefix_reused = plan["prefix"]
+                self.counters["prompt_tokens"] += len(r.prompt)
+                self.counters["prefill_tokens"] += plan["suffix"]
+                self.counters["prefix_tokens_reused"] += plan["prefix"]
+                self.counters["prefix_hits"] += 1 if plan["prefix"] > 0 else 0
+                self._note_pool()
 
     def _chunked_step(self, finished: List[Request]) -> None:
         """One mixed-budget engine step (DESIGN.md §3.10): admit, pack decode
@@ -1189,16 +1212,18 @@ class ServeEngine:
             # chunk-quantized pool that is enough to flip an argmax tie.
             # Speculative chunked serving also keeps the ragged launch: draft
             # windows need the per-row causal mask every step.
-            cur = jnp.asarray(self._pos + 1, jnp.int32)
-            tok, self.caches = self._decode_step(
-                self.params, jnp.asarray(self._pending), self.caches, cur,
-                self._next_key())
-            tok = np.asarray(tok)
+            with spans.span("engine.launch"):
+                cur = jnp.asarray(self._pos + 1, jnp.int32)
+                tok, self.caches = self._decode_step(
+                    self.params, jnp.asarray(self._pending), self.caches, cur,
+                    self._next_key())
+            tok = self._read(tok)
             self._pos[gen] += 1
             self.counters["decode_steps"] += 1
             self.counters["active_slot_steps"] += len(gen)
-            for i in gen:
-                self._emit(i, int(tok[i]), finished)
+            with spans.span("engine.emit"):
+                for i in gen:
+                    self._emit(i, int(tok[i]), finished)
             return
         Nt = self.token_budget
         toks = np.zeros(Nt, np.int32)
@@ -1246,11 +1271,12 @@ class ServeEngine:
             slot_ids[off: off + end - start] = i
             q_start[i], q_len[i], kv_len[i] = off, end - start, end
             off += end - start
-        tok, rowmax, self.caches = self._chunk_step(
-            self.params, jnp.asarray(toks[None]), jnp.asarray(q_start),
-            jnp.asarray(q_len), jnp.asarray(kv_len), jnp.asarray(positions),
-            jnp.asarray(slot_ids), self.caches, self._next_key())
-        tok, rowmax = np.asarray(tok), np.asarray(rowmax)
+        with spans.span("engine.launch"):
+            tok, rowmax, self.caches = self._chunk_step(
+                self.params, jnp.asarray(toks[None]), jnp.asarray(q_start),
+                jnp.asarray(q_len), jnp.asarray(kv_len), jnp.asarray(positions),
+                jnp.asarray(slot_ids), self.caches, self._next_key())
+        tok, rowmax = self._read(tok, rowmax)
         self.counters["chunk_steps"] += 1
         self.counters["chunk_decode_rows"] += int(sum(wl[i] for i in gen))
         if gen:
@@ -1267,55 +1293,64 @@ class ServeEngine:
         if self.spec > 1 and gen:
             self.counters["spec_steps"] += 1
             self.counters["spec_slot_steps"] += len(gen)
-        for i in gen:
-            if self.spec > 1:
-                r = self._slots[i]
-                out_w = rowmax[q_start[i]: q_start[i] + wl[i]]
-                n = 1                                  # pending always lands
-                while n < wl[i] and toks[q_start[i] + n] == out_w[n - 1]:
-                    n += 1
-                self.counters["spec_drafted"] += int(wl[i]) - 1
-                self.counters["spec_accepted"] += n - 1
-                for j in range(n):
+        with spans.span("engine.emit"):
+            for i in gen:
+                if self.spec > 1:
+                    r = self._slots[i]
+                    out_w = rowmax[q_start[i]: q_start[i] + wl[i]]
+                    n = 1                                  # pending always lands
+                    while n < wl[i] and toks[q_start[i] + n] == out_w[n - 1]:
+                        n += 1
+                    self.counters["spec_drafted"] += int(wl[i]) - 1
+                    self.counters["spec_accepted"] += n - 1
+                    for j in range(n):
+                        self._pos[i] += 1
+                        self._emit(i, int(out_w[j]), finished)
+                        self.counters["spec_emitted"] += 1
+                        if self._slots[i] is not r:
+                            assert (not self._seq_pages[i]
+                                    and (self._table[i] == self.n_pages).all()), \
+                                "mid-window retirement left stale page mappings"
+                            break
+                else:
                     self._pos[i] += 1
-                    self._emit(i, int(out_w[j]), finished)
-                    self.counters["spec_emitted"] += 1
-                    if self._slots[i] is not r:
-                        assert (not self._seq_pages[i]
-                                and (self._table[i] == self.n_pages).all()), \
-                            "mid-window retirement left stale page mappings"
-                        break
-            else:
-                self._pos[i] += 1
-                self._emit(i, int(tok[i]), finished)
-        # ---- mid-prefill slots: advance; final chunk emits the first token
-        for i in served_pre:
-            end = int(kv_len[i])
-            self._prefill_off[i] = end
-            if end == self._prefill_target[i]:
-                r = self._slots[i]
-                self._prefill_target[i] = 0
-                self._pos[i] = len(r.prompt)
-                if self.radix is not None:
-                    # the full prompt is on device now: register its pages as
-                    # a cached prefix (same point the admit step does it)
-                    self.radix.insert(r.prompt,
-                                      self._seq_pages[i][: len(r.prompt)
-                                                         // self.ps],
-                                      self.pool)
-                self._emit(i, int(tok[i]), finished)
+                    self._emit(i, int(tok[i]), finished)
+            # ---- mid-prefill slots: advance; final chunk emits the first token
+            for i in served_pre:
+                end = int(kv_len[i])
+                self._prefill_off[i] = end
+                if end == self._prefill_target[i]:
+                    r = self._slots[i]
+                    self._prefill_target[i] = 0
+                    self._pos[i] = len(r.prompt)
+                    if self.radix is not None:
+                        # the full prompt is on device now: register its pages as
+                        # a cached prefix (same point the admit step does it)
+                        self.radix.insert(r.prompt,
+                                          self._seq_pages[i][: len(r.prompt)
+                                                             // self.ps],
+                                          self.pool)
+                    self._emit(i, int(tok[i]), finished)
 
     def step(self, finished: List[Request]) -> bool:
         """One engine iteration: admissions plus at most one model launch.
         Appends retired requests to ``finished``; returns False once the
         engine is idle (empty queue, no slots in flight). Exposed so callers
         — the latency benchmark drives this directly — can time individual
-        steps and inject mid-run traffic between them."""
+        steps and inject mid-run traffic between them. Spanned as
+        ``engine.step`` and timed into ``counters["step_ns"]``."""
         if not (self.queue or any(s is not None for s in self._slots)):
             return False
-        if self.chunked:
-            self._chunked_step(finished)
-            return True
+        self._n_steps += 1
+        with spans.span("engine.step", self.counters, "step_ns",
+                        step_num=self._n_steps):
+            if self.chunked:
+                self._chunked_step(finished)
+            else:
+                self._step_once(finished)
+        return True
+
+    def _step_once(self, finished: List[Request]) -> None:
         self._admit(finished)
         active = [i for i, s in enumerate(self._slots) if s is not None]
         if not active:
@@ -1328,23 +1363,24 @@ class ServeEngine:
                     f"(prompt {len(self.queue[0].prompt)} + budget "
                     f"{self.queue[0].max_new})")
             assert not self.queue, "scheduler stalled with queued requests"
-            return True   # everything admitted retired at its first token
+            return        # everything admitted retired at its first token
         if self.paged and self._table_dirty:
             self._push_table()
         if self.spec > 1:
             self._spec_step(active, finished)
-            return True
-        cur = jnp.asarray(self._pos + 1, jnp.int32)   # post-append lengths
-        tok, self.caches = self._decode_step(
-            self.params, jnp.asarray(self._pending), self.caches, cur,
-            self._next_key())
-        tok = np.asarray(tok)
+            return
+        with spans.span("engine.launch"):
+            cur = jnp.asarray(self._pos + 1, jnp.int32)   # post-append lengths
+            tok, self.caches = self._decode_step(
+                self.params, jnp.asarray(self._pending), self.caches, cur,
+                self._next_key())
+        tok = self._read(tok)
         self._pos[active] += 1
         self.counters["decode_steps"] += 1
         self.counters["active_slot_steps"] += len(active)
-        for i in active:
-            self._emit(i, int(tok[i]), finished)
-        return True
+        with spans.span("engine.emit"):
+            for i in active:
+                self._emit(i, int(tok[i]), finished)
 
     def run(self) -> List[Request]:
         finished: List[Request] = []

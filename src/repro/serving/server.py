@@ -61,6 +61,7 @@ from repro.runtime.supervisor import (FailureInjector, ReplicaHealth,
                                       RestartTracker)
 from repro.serving.api import (AdmissionError, FinishReason, Request,
                                RequestMetrics, StreamEvent)
+from repro.serving import spans
 from repro.serving.config import EngineConfig
 from repro.serving.engine import ServeEngine
 from repro.serving.engine import Request as EngineRequest
@@ -274,29 +275,31 @@ class _Replica:
             engine.step(finished)
 
     def _drain(self, engine: ServeEngine) -> None:
-        while True:
-            with self.lock:
-                if not self.inbox:
-                    return
-                rec = self.inbox.popleft()
-            now = time.monotonic()
-            if rec.admit_t is None:
-                rec.admit_t = now
-            prompt = np.concatenate(
-                [np.asarray(rec.req.prompt, np.int32),
-                 np.asarray(rec.emitted, np.int32)]) \
-                if rec.emitted else np.asarray(rec.req.prompt, np.int32)
-            max_new = rec.req.max_new - len(rec.emitted)
-            try:
-                ereq = engine.submit([prompt], max_new=max_new)[0]
-            except ValueError as e:     # e.g. prompt longer than the cache
-                # count before posting: a consumer that saw the terminal frame
-                # must find the counters already settled
-                self.server._note_done(rec, completed=False)
-                self._post(rec, StreamEvent(kind="error", rid=rec.rid,
-                                            error=str(e)))
-                continue
-            self.tracked[ereq.rid] = rec
+        with spans.span("replica.drain"):
+            while True:
+                with self.lock:
+                    if not self.inbox:
+                        return
+                    rec = self.inbox.popleft()
+                now = time.monotonic()
+                if rec.admit_t is None:
+                    rec.admit_t = now
+                prompt = np.concatenate(
+                    [np.asarray(rec.req.prompt, np.int32),
+                     np.asarray(rec.emitted, np.int32)]) \
+                    if rec.emitted else np.asarray(rec.req.prompt, np.int32)
+                max_new = rec.req.max_new - len(rec.emitted)
+                try:
+                    ereq = engine.submit([prompt], max_new=max_new)[0]
+                except ValueError as e:     # e.g. prompt longer than the cache
+                    # count before posting: a consumer that saw the terminal
+                    # frame must find the counters already settled
+                    self.server._note_done(rec, completed=False)
+                    self._post(rec, StreamEvent(kind="error", rid=rec.rid,
+                                                error=str(e)))
+                    continue
+                ereq.tag = rec.rid
+                self.tracked[ereq.rid] = rec
 
     def _on_token(self, r: EngineRequest, tok: int) -> None:
         rec = self.tracked.get(r.rid)
@@ -473,17 +476,18 @@ class AsyncServer:
             return False
         need = self._worst_case_pages(request)
         seen = False
-        for r in self.replicas:
-            eng = r.engine
-            if not r.alive or eng is None or getattr(eng, "pool", None) is None:
-                continue
-            seen = True
-            avail = eng.pool.free_count
-            if eng.radix is not None:
-                avail += sum(1 for p in eng.radix.held_pages()
-                             if eng.pool.refs[p] == 1)
-            if avail >= need:
-                return False
+        with spans.span("server.pool_check"):
+            for r in self.replicas:
+                eng = r.engine
+                if not r.alive or eng is None or getattr(eng, "pool", None) is None:
+                    continue
+                seen = True
+                avail = eng.pool.free_count
+                if eng.radix is not None:
+                    avail += sum(1 for p in eng.radix.held_pages()
+                                 if eng.pool.refs[p] == 1)
+                if avail >= need:
+                    return False
         return seen
 
     async def submit(self, request: Request) -> AsyncIterator[StreamEvent]:
@@ -499,30 +503,39 @@ class AsyncServer:
         t0 = time.monotonic()
         deadline = t0 + self.admission_timeout
         async with self._cond:
-            while True:
-                queue_ok = self._inflight < self.max_queue
-                if queue_ok and not self._pool_blocked(request):
-                    break
-                reason = "queue_full" if not queue_ok else "pool_pressure"
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    with self._stats_lock:
-                        self.counters["rejected"] += 1
-                    what = (f"admission queue full ({self.max_queue} in flight)"
-                            if reason == "queue_full" else
-                            f"page-pool pressure ({self._worst_case_pages(request)}"
-                            f" pages needed, no alive replica can cover it)")
-                    raise AdmissionError(
-                        f"{what} past {self.admission_timeout:.3g}s deadline",
-                        queue_wait_s=time.monotonic() - t0, reason=reason)
-                try:
-                    # In-flight count changes notify this condition; page-pool
-                    # occupancy changes on the replica threads, which do not —
-                    # so wait on a short tick and re-poll the pools.
-                    await asyncio.wait_for(self._cond.wait(),
-                                           timeout=min(remaining, 0.05))
-                except asyncio.TimeoutError:
-                    pass
+            # one span over the whole wait; ``reason`` names each bound that
+            # held, in turn, and is absent where the request passed at once
+            with spans.span("server.admission_wait", rid=rid) as sp:
+                held = None
+                while True:
+                    queue_ok = self._inflight < self.max_queue
+                    if queue_ok and not self._pool_blocked(request):
+                        break
+                    reason = "queue_full" if not queue_ok else "pool_pressure"
+                    if reason != held:
+                        sp.set_metadata(reason=reason)
+                        held = reason
+                    remaining = deadline - time.monotonic()
+                    if remaining <= 0:
+                        with self._stats_lock:
+                            self.counters["rejected"] += 1
+                        what = (f"admission queue full ({self.max_queue} in flight)"
+                                if reason == "queue_full" else
+                                f"page-pool pressure "
+                                f"({self._worst_case_pages(request)} pages "
+                                f"needed, no alive replica can cover it)")
+                        raise AdmissionError(
+                            f"{what} past {self.admission_timeout:.3g}s deadline",
+                            queue_wait_s=time.monotonic() - t0, reason=reason)
+                    try:
+                        # In-flight count changes notify this condition;
+                        # page-pool occupancy changes on the replica threads,
+                        # which do not — so wait on a short tick and re-poll
+                        # the pools.
+                        await asyncio.wait_for(self._cond.wait(),
+                                               timeout=min(remaining, 0.05))
+                    except asyncio.TimeoutError:
+                        pass
             self._inflight += 1
         with self._stats_lock:
             self.counters["submitted"] += 1

@@ -328,7 +328,7 @@ class TestChunkedEngineParity:
     def test_radix_stats_match(self, small):
         _, b = _serve(small, "fake", "fp")
         _, c = _serve(small, "fake", "fp", chunked=True, token_budget=12)
-        assert c.prefix_hit_rate() == b.prefix_hit_rate()
+        assert c.stats().prefix_hit_rate == b.stats().prefix_hit_rate
 
     def test_int8_pool_divergence_is_bounded(self, small):
         """First chunk lands bit-identically; later chunks drift by at most a

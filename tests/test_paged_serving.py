@@ -148,7 +148,7 @@ class TestPrefixReuse:
                           kv_cache=kv)
         assert warm == cold == dense, (path, kv)
         assert ew.counters["prefix_hits"] > 0
-        assert ew.prefix_hit_rate() > 0.0
+        assert ew.stats().prefix_hit_rate > 0.0
         assert ec.counters["prefix_hits"] == 0
         assert ew.counters["prefill_tokens"] < ec.counters["prefill_tokens"]
         assert (ew.counters["prefill_tokens"] + ew.counters["prefix_tokens_reused"]

@@ -166,7 +166,7 @@ CODE = textwrap.dedent("""
     spec_base, _ = serve_spec(None, 1)
     spec_got, eng = serve_spec(mesh2, 4)
     ok = spec_got == spec_base and eng.counters["spec_accepted"] > 0
-    print(f"spec tp=2 fused-int8/int8 paged accept={eng.accept_rate():.2f}: "
+    print(f"spec tp=2 fused-int8/int8 paged accept={eng.stats().accept_rate:.2f}: "
           f"{'OK' if ok else 'MISMATCH ' + repr((spec_got, spec_base))}",
           flush=True)
     if not ok:

@@ -104,8 +104,8 @@ class TestEngineParity:
         cfg, params, _ = small
         spec, eng = _serve(cfg, params, _spec_prompts(cfg), MAX_NEW, speculate=4)
         assert eng.counters["spec_accepted"] > 0
-        assert eng.accept_rate() > 0.0
-        assert eng.tokens_per_step() > 1.0
+        assert eng.stats().accept_rate > 0.0
+        assert eng.stats().tokens_per_step > 1.0
 
     def test_window_sizes_agree(self, small):
         """Every window size k (incl. k=1 == plain engine) yields the same
