@@ -255,7 +255,7 @@ def kernel_checks(cfg, seed):
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels import ops, ref
+    from repro.kernels import ops, paged_layout, ref
 
     rng = np.random.default_rng(seed + 3)
     C = PREFILL_SHAPE[0] * PREFILL_SHAPE[1]
@@ -288,6 +288,10 @@ def kernel_checks(cfg, seed):
     ks, vs = (jnp.asarray(0.002 + 0.05 * rng.random((P, ps, Hkv, 1)), jnp.float32)
               for _ in range(2))
     scales = dict(k_scale_pages=ks, v_scale_pages=vs)
+    # the kernels take the pools as the engine stores them, the oracles the
+    # logical view
+    skp, svp, sks, svs = paged_layout.stored(kp, vp, ks, vs)
+    stored = dict(k_scale_pages=sks, v_scale_pages=svs)
     # injective page tables; slot b holds kv_len[b] tokens
     kv_len = np.asarray([1, 7, 8, 9, 100, 255, 300, MAX_LEN], np.int32)
     table = np.full((BATCH, maxP), P, np.int32)
@@ -300,14 +304,14 @@ def kernel_checks(cfg, seed):
         q = bf16(rng.standard_normal((BATCH, 1, Hkv * G, D)))
         compare_kernel(
             "paged decode (int8 KV)",
-            ops.paged_decode_attention(q, kp, vp, table, kvl, **scales),
+            ops.paged_decode_attention(q, skp, svp, table, kvl, **stored),
             ref.paged_decode_attention_ref(q.reshape(BATCH, Hkv, G, D), kp, vp,
                                            table, kvl, **scales)
             .reshape(BATCH, 1, Hkv * G, D))
         W = 4
         qln = jnp.asarray(np.minimum(kv_len, [1, 2, 3, 4, 4, 4, 2, 4]), jnp.int32)
         q = bf16(rng.standard_normal((BATCH, W, Hkv * G, D)))
-        got = ops.paged_verify_attention(q, kp, vp, table, kvl, qln, **scales)
+        got = ops.paged_verify_attention(q, skp, svp, table, kvl, qln, **stored)
         want = jnp.transpose(ref.paged_verify_attention_ref(
             jnp.transpose(q.reshape(BATCH, W, Hkv, G, D), (0, 2, 1, 3, 4)),
             kp, vp, table, kvl, qln, **scales), (0, 2, 1, 3, 4))
@@ -321,10 +325,12 @@ def kernel_checks(cfg, seed):
         Nt = TOKEN_BUDGET
         q = bf16(rng.standard_normal((Nt, Hkv * G, D)))
         kn, vn = (bf16(rng.standard_normal((Nt, Hkv, D))) for _ in range(2))
-        args = (kn, vn, kp, vp, table, jnp.asarray(q_start), jnp.asarray(q_len), kvl)
-        got = ops.ragged_prefill_attention(q, *args, chunk_cap=Nt, **scales)
-        want = ref.ragged_prefill_attention_ref(q.reshape(Nt, Hkv, G, D), *args,
-                                                chunk_cap=Nt, **scales)
+        rest = (table, jnp.asarray(q_start), jnp.asarray(q_len), kvl)
+        got = ops.ragged_prefill_attention(q, kn, vn, skp, svp, *rest,
+                                           chunk_cap=Nt, **stored)
+        want = ref.ragged_prefill_attention_ref(q.reshape(Nt, Hkv, G, D), kn, vn,
+                                                kp, vp, *rest, chunk_cap=Nt,
+                                                **scales)
         n = int(q_len.sum())
         compare_kernel("ragged prefill (int8 KV, budget 256)",
                        np.asarray(got, np.float32)[:n],

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import paged_layout
+
 NEG_INF = -1e30
 # query rows (chunk tokens × groups) per ragged-prefill grid step
 RAGGED_ROW_TILE = 128
@@ -97,26 +99,23 @@ def _fa_kernel(q_ref, k_ref, v_ref, *refs,
         o_ref[0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
 
 
-def _scale_rows(scale: jax.Array) -> jax.Array:
-    """(P, Hkv, ps) per-token scales → (P, 1, L) f32 rows, lane ``h·ps + t``,
-    zero-padded to L = a multiple of 128: Mosaic DMAs an HBM tile only when
-    its minor dimension is lane-aligned, and one page's scales (Hkv·ps values)
-    are fewer than 128 lanes at the engine's page sizes."""
-    P, Hkv, ps = scale.shape
-    rows = scale.astype(jnp.float32).reshape(P, 1, Hkv * ps)
-    return jnp.pad(rows, ((0, 0), (0, 0), (0, (-(Hkv * ps)) % 128)))
+def _check_scales(k_scale, v_scale, P: int, ps: int, n_kv_heads: int):
+    want = (P, 1, paged_layout.scale_width(n_kv_heads, ps))
+    assert k_scale.shape == v_scale.shape == want, (k_scale.shape, want)
 
 
 def _walk_pages(tab_ref, b, n_pages_max: int, n_live, k_hbm, v_hbm, s_hbm,
                 page_step, init, finish):
     """Double-buffered walk over slot ``b``'s first ``n_live`` pages.
 
-    The pools stay in HBM (``memory_space=ANY``) as lane-dense (P, ps, Hkv·D)
-    tiles; while page ``j`` computes, page ``j+1``'s K/V tiles (and, int8-KV,
-    its two (1, L) scale rows from ``s_hbm``) are already in flight into the
-    spare VMEM slot. Page indices come from the scalar-prefetched flattened
-    (B·max_pages,) page table in SMEM, so the dense (B, T, Hkv, D) view is
-    never materialized and pages past ``n_live`` are never fetched.
+    The pools stay in HBM (``memory_space=ANY``) in their stored layout
+    (``kernels/paged_layout.py``): a page is one lane-dense (ps, Hkv·D) tile,
+    DMA'd as it lies. While page ``j`` computes, page ``j+1``'s K/V tiles
+    (and, int8-KV, its two (1, L) scale rows from ``s_hbm``) are already in
+    flight into the spare VMEM slot. Page indices come from the
+    scalar-prefetched flattened (B·max_pages,) page table in SMEM, so the
+    dense (B, T, Hkv, D) view is never materialized and pages past ``n_live``
+    are never fetched.
 
     Sentinel entries (≥ P) clamp to a valid page. For live rows the clamp is
     unreachable below kv_len (the engine maps every valid position to a real
@@ -300,20 +299,20 @@ def paged_decode_attention_pallas(
     window: Optional[int] = None, softcap: Optional[float] = None,
     interpret: bool = False,
 ) -> jax.Array:
-    """q: (B, Hkv, q_win·G, D); k/v pages: (P, ps, Hkv, D); page_table:
-    (B, maxP) int32 (entries ≥ P are invalid — clamped in the kernel and
-    masked by ``kv_len``); kv_len: (B,) int32 with kv_len ≤ maxP·ps
-    → (B, Hkv, q_win·G, D). The pools stay in HBM, viewed lane-dense as
-    (P, ps, Hkv·D); the kernel DMAs each live page's tile on demand
-    (double-buffered — see ``_walk_pages``).
+    """q: (B, Hkv, q_win·G, D); k/v pages: (P, ps, Hkv·D), the stored
+    lane-dense layout (``kernels/paged_layout.py``: lane ``h·D + d``);
+    page_table: (B, maxP) int32 (entries ≥ P are invalid — clamped in the
+    kernel and masked by ``kv_len``); kv_len: (B,) int32 with kv_len ≤ maxP·ps
+    → (B, Hkv, q_win·G, D). The pools stay in HBM as they are stored; the
+    kernel DMAs each live page's tile on demand (double-buffered — see
+    ``_walk_pages``), so no call relays out a pool.
 
-    ``k_scale``/``v_scale`` (both or neither): int8-KV per-token scales in the
-    (P, Hkv, ps) row layout — ``ops.paged_decode_attention`` transposes the
-    engine's (P, ps, Hkv, 1) scale pools, D× smaller than the code pools.
-    They travel as one lane-aligned row per page (``_scale_rows``) on the
-    same per-page DMA pipeline as the code tiles and apply in-kernel at the
-    score/prob level (dense ``decode_attention`` numerics) — the int8 path
-    never materializes a dense (B, T, ...) view either.
+    ``k_scale``/``v_scale`` (both or neither): int8-KV per-token scale pools
+    in their stored (P, 1, L) f32 row layout (lane ``h·ps + t``, zero-padded
+    to a multiple of 128). A page's row travels on the same per-page DMA
+    pipeline as its code tiles and applies in-kernel at the score/prob level
+    (dense ``decode_attention`` numerics) — the int8 path never materializes
+    a dense (B, T, ...) view either.
 
     ``q_win > 1`` + ``q_len`` (B,) int32: draft-window verify (DESIGN.md
     §3.9). q's third axis carries ``q_win`` window tokens × G group heads in
@@ -325,6 +324,8 @@ def paged_decode_attention_pallas(
     assert R % q_win == 0, (R, q_win)
     P, ps = k_pages.shape[0], k_pages.shape[1]
     maxP = page_table.shape[1]
+    assert k_pages.shape == v_pages.shape == (P, ps, Hkv * D), (
+        k_pages.shape, (P, ps, Hkv * D))
     assert page_table.shape == (B, maxP) and kv_len.shape == (B,)
     assert (q_len is not None) == (q_win > 1), "q_len iff q_win > 1"
     kv_int8 = k_scale is not None
@@ -341,12 +342,11 @@ def paged_decode_attention_pallas(
         pl.BlockSpec(memory_space=pl.ANY),        # k pool, paged via DMA
         pl.BlockSpec(memory_space=pl.ANY),        # v pool
     ]
-    args = [q, k_pages.reshape(P, ps, Hkv * D), v_pages.reshape(P, ps, Hkv * D)]
+    args = [q, k_pages, v_pages]
     if kv_int8:
-        assert k_scale.shape == v_scale.shape == (P, Hkv, ps), (
-            k_scale.shape, (P, Hkv, ps))
+        _check_scales(k_scale, v_scale, P, ps, Hkv)
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
+        args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=n_pref,
         grid=(B,),
@@ -476,6 +476,8 @@ def ragged_prefill_attention_pallas(
     P, ps = k_pages.shape[0], k_pages.shape[1]
     B, maxP = page_table.shape
     C = chunk_cap
+    assert k_pages.shape == v_pages.shape == (P, ps, Hkv * D), (
+        k_pages.shape, (P, ps, Hkv * D))
     assert k_new.shape == v_new.shape == (N, Hkv, D), (k_new.shape, q.shape)
     assert q_start.shape == q_len.shape == kv_len.shape == (B,)
     assert C >= 1, C
@@ -516,13 +518,11 @@ def ragged_prefill_attention_pallas(
         pl.BlockSpec(memory_space=pl.ANY),        # k pool, paged via DMA
         pl.BlockSpec(memory_space=pl.ANY),        # v pool
     ]
-    args = [q_ld, kn, vn, k_pages.reshape(P, ps, Hkv * D),
-            v_pages.reshape(P, ps, Hkv * D)]
+    args = [q_ld, kn, vn, k_pages, v_pages]
     if kv_int8:
-        assert k_scale.shape == v_scale.shape == (P, Hkv, ps), (
-            k_scale.shape, (P, Hkv, ps))
+        _check_scales(k_scale, v_scale, P, ps, Hkv)
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        args += [_scale_rows(k_scale), _scale_rows(v_scale)]
+        args += [k_scale, v_scale]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(B, n_tiles),

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.kernels import act_quantize as _aq
 from repro.kernels import flash_attention as _fa
+from repro.kernels import paged_layout as _layout
 from repro.kernels import qgemm as _qg
 from repro.kernels import ref as _ref
 from repro.sharding import hints
@@ -219,13 +220,27 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, kv_len=None, *,
                             mesh=hints.current_mesh())
 
 
+def _logical_pools(k_pages, v_pages, k_scale_pages, v_scale_pages,
+                   n_kv_heads: int):
+    """The ref route's view of the stored pools (``kernels/paged_layout.py``):
+    [(P, ps, Hkv, D) codes ×2] and the (P, ps, Hkv, 1) scale-pool kwargs of
+    the oracles (empty on fp pools)."""
+    kv = [_layout.codes_view(p, n_kv_heads) for p in (k_pages, v_pages)]
+    if k_scale_pages is None:
+        return kv, {}
+    ps = k_pages.shape[1]
+    return kv, dict(
+        k_scale_pages=_layout.scales_view(k_scale_pages, n_kv_heads, ps),
+        v_scale_pages=_layout.scales_view(v_scale_pages, n_kv_heads, ps))
+
+
 @functools.partial(jax.jit, static_argnames=("window", "softcap", "mesh",
                                              "exec_mode"))
 def _paged_decode_attention(q, k_pages, v_pages, page_table, kv_len,
                             k_scale_pages, v_scale_pages, *,
                             window, softcap, mesh, exec_mode):
     B, S, H, D = q.shape
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages.shape[2] // D
     G = H // Hkv
 
     def body(q, k_pages, v_pages, page_table, kv_len, k_scale_pages,
@@ -234,22 +249,14 @@ def _paged_decode_attention(q, k_pages, v_pages, page_table, kv_len,
         kvl = jnp.broadcast_to(
             jnp.reshape(kv_len, (-1,)).astype(jnp.int32), (B,))
         if exec_mode == "ref":
+            kv, sc = _logical_pools(k_pages, v_pages, k_scale_pages,
+                                    v_scale_pages, Hkv)
             out = _ref.paged_decode_attention_ref(
-                qg, k_pages, v_pages, page_table, kvl,
-                k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
-                window=window, softcap=softcap)
+                qg, *kv, page_table, kvl, **sc, window=window, softcap=softcap)
             return out.reshape(B, 1, H, D)
-        ks = vs = None
-        if k_scale_pages is not None:
-            # (P, ps, Hkv, 1) scale pools → the kernel's (P, Hkv, ps) row
-            # tiles. The transpose touches scale bytes only (D× less than the
-            # code pools) and runs inside the manual region, so the partitioner
-            # never sees it (DESIGN.md §3.7 interpret-emulation caveat).
-            ks = jnp.transpose(k_scale_pages[..., 0], (0, 2, 1))
-            vs = jnp.transpose(v_scale_pages[..., 0], (0, 2, 1))
         out = _fa.paged_decode_attention_pallas(
             qg, k_pages, v_pages, page_table, kvl,
-            k_scale=ks, v_scale=vs,
+            k_scale=k_scale_pages, v_scale=v_scale_pages,
             window=window, softcap=softcap, interpret=_interpret())
         return out.reshape(B, 1, H, D)
 
@@ -263,17 +270,19 @@ def paged_decode_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                            k_scale_pages=None, v_scale_pages=None,
                            window=None, softcap=None) -> jax.Array:
     """Paged single-token decode attention (DESIGN.md §3.8): q (B,1,H,D) against
-    (P, ps, Hkv, D) pools addressed through a (B, maxP) int32 page table with
-    per-slot valid lengths ``kv_len`` (scalar or (B,)) → (B,1,H,D).
+    pools in their stored layout (``kernels/paged_layout.py``: (P, ps, Hkv·D)
+    codes) addressed through a (B, maxP) int32 page table with per-slot valid
+    lengths ``kv_len`` (scalar or (B,)) → (B,1,H,D).
 
-    The kernel gathers each logical page's physical K/V tile via scalar-prefetch
-    page indices — the dense (B, T, Hkv, D) view is never materialized. With
-    ``k_scale_pages``/``v_scale_pages`` ((P, ps, Hkv, 1) f32) the pools hold
-    int8 codes: the per-token scale tiles ride the same prefetched page indices
+    The pools pass to the kernel untouched: it DMAs each logical page's
+    physical tile via scalar-prefetch page indices, so neither a relayout of
+    the pool nor the dense (B, T, Hkv, D) view is ever materialized. With
+    ``k_scale_pages``/``v_scale_pages`` ((P, 1, L) f32 rows) the pools hold
+    int8 codes: the per-token scale rows ride the same prefetched page indices
     and apply in-kernel at the score/prob level, the exact application points of
     the dense ``layers.decode_attention`` int8 path — every paged decode path
     (fp, int8-KV) serves through this kernel. Under a TP-sharded serving plan
-    the body (scale-pool relayout included) runs as one GSPMD-manual region."""
+    the body runs as one GSPMD-manual region."""
     return _paged_decode_attention(q, k_pages, v_pages, page_table, kv_len,
                                    k_scale_pages, v_scale_pages,
                                    window=window, softcap=softcap,
@@ -287,29 +296,26 @@ def _paged_verify_attention(q, k_pages, v_pages, page_table, kv_len, q_len,
                             k_scale_pages, v_scale_pages, *,
                             window, softcap, mesh, exec_mode):
     B, W, H, D = q.shape
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages.shape[2] // D
     G = H // Hkv
 
     def body(q, k_pages, v_pages, page_table, kv_len, q_len, k_scale_pages,
              v_scale_pages):
         if exec_mode == "ref":
+            kv, sc = _logical_pools(k_pages, v_pages, k_scale_pages,
+                                    v_scale_pages, Hkv)
             out = _ref.paged_verify_attention_ref(
                 jnp.transpose(q.reshape(B, W, Hkv, G, D), (0, 2, 1, 3, 4)),
-                k_pages, v_pages, page_table,
+                *kv, page_table,
                 jnp.broadcast_to(
                     jnp.reshape(kv_len, (-1,)).astype(jnp.int32), (B,)),
                 jnp.broadcast_to(
                     jnp.reshape(q_len, (-1,)).astype(jnp.int32), (B,)),
-                k_scale_pages=k_scale_pages, v_scale_pages=v_scale_pages,
-                window=window, softcap=softcap)
+                **sc, window=window, softcap=softcap)
             return jnp.transpose(out, (0, 2, 1, 3, 4)).reshape(B, W, H, D)
         # (B, W, H, D) → the kernel's (window, group)-ordered score-tile rows
         qg = jnp.transpose(q.reshape(B, W, Hkv, G, D),
                            (0, 2, 1, 3, 4)).reshape(B, Hkv, W * G, D)
-        ks = vs = None
-        if k_scale_pages is not None:
-            ks = jnp.transpose(k_scale_pages[..., 0], (0, 2, 1))
-            vs = jnp.transpose(v_scale_pages[..., 0], (0, 2, 1))
         # W == 1 forces q_len == 1 everywhere, and the verify mask at
         # q_len == 1 reduces exactly to the decode mask — dispatch to the
         # plain decode launch (no q_len prefetch operand)
@@ -318,7 +324,7 @@ def _paged_verify_attention(q, k_pages, v_pages, page_table, kv_len, q_len,
         out = _fa.paged_decode_attention_pallas(
             qg, k_pages, v_pages, page_table,
             jnp.broadcast_to(jnp.reshape(kv_len, (-1,)).astype(jnp.int32), (B,)),
-            k_scale=ks, v_scale=vs, **qw,
+            k_scale=k_scale_pages, v_scale=v_scale_pages, **qw,
             window=window, softcap=softcap, interpret=_interpret())
         return jnp.transpose(out.reshape(B, Hkv, W, G, D),
                              (0, 2, 1, 3, 4)).reshape(B, W, H, D)
@@ -334,8 +340,8 @@ def paged_verify_attention(q: jax.Array, k_pages: jax.Array, v_pages: jax.Array,
                            k_scale_pages=None, v_scale_pages=None,
                            window=None, softcap=None) -> jax.Array:
     """Paged draft-window verify attention (DESIGN.md §3.9): q (B, W, H, D) —
-    W window tokens per slot, already scattered into the (P, ps, Hkv, D) pools
-    — against the same page table / pools as ``paged_decode_attention``, with
+    W window tokens per slot, already scattered into the stored pools —
+    against the same page table / pools as ``paged_decode_attention``, with
     per-slot total post-scatter length ``kv_len`` and valid window rows
     ``q_len`` (window token i sits at ``kv_len - q_len + i``; rows ≥ q_len are
     garbage-but-finite) → (B, W, H, D).
@@ -359,36 +365,32 @@ def _ragged_prefill_attention(q, k_new, v_new, k_pages, v_pages, page_table,
                               v_scale_pages, *, chunk_cap, window, softcap,
                               mesh, exec_mode):
     Nt, H, D = q.shape
-    Hkv = k_pages.shape[2]
+    Hkv = k_pages.shape[2] // D
     G = H // Hkv
 
     def body(q, k_new, v_new, k_pages, v_pages, page_table, q_start, q_len,
              kv_len, k_scale_pages, v_scale_pages):
         B = page_table.shape[0]
         if exec_mode == "ref":
+            kv, sc = _logical_pools(k_pages, v_pages, k_scale_pages,
+                                    v_scale_pages, Hkv)
             out = _ref.ragged_prefill_attention_ref(
-                q.reshape(Nt, Hkv, G, D), k_new, v_new, k_pages, v_pages,
-                page_table,
+                q.reshape(Nt, Hkv, G, D), k_new, v_new, *kv, page_table,
                 jnp.reshape(q_start, (-1,)).astype(jnp.int32),
                 jnp.broadcast_to(
                     jnp.reshape(q_len, (-1,)).astype(jnp.int32), (B,)),
                 jnp.broadcast_to(
                     jnp.reshape(kv_len, (-1,)).astype(jnp.int32), (B,)),
-                chunk_cap=chunk_cap, k_scale_pages=k_scale_pages,
-                v_scale_pages=v_scale_pages, window=window, softcap=softcap)
+                chunk_cap=chunk_cap, **sc, window=window, softcap=softcap)
             return out.reshape(Nt, H, D)
         # packed (Nt, H, D) → the kernel's head-major (Hkv, Nt, G, D)
         qg = jnp.transpose(q.reshape(Nt, Hkv, G, D), (1, 0, 2, 3))
-        ks = vs = None
-        if k_scale_pages is not None:
-            ks = jnp.transpose(k_scale_pages[..., 0], (0, 2, 1))
-            vs = jnp.transpose(v_scale_pages[..., 0], (0, 2, 1))
         out = _fa.ragged_prefill_attention_pallas(
             qg, k_new, v_new, k_pages, v_pages, page_table,
             jnp.reshape(q_start, (-1,)).astype(jnp.int32),
             jnp.broadcast_to(jnp.reshape(q_len, (-1,)).astype(jnp.int32), (B,)),
             jnp.broadcast_to(jnp.reshape(kv_len, (-1,)).astype(jnp.int32), (B,)),
-            chunk_cap=chunk_cap, k_scale=ks, v_scale=vs,
+            chunk_cap=chunk_cap, k_scale=k_scale_pages, v_scale=v_scale_pages,
             window=window, softcap=softcap, interpret=_interpret())
         return jnp.transpose(out, (1, 0, 2, 3)).reshape(Nt, H, D)
 
@@ -407,9 +409,9 @@ def ragged_prefill_attention(q: jax.Array, k_new: jax.Array, v_new: jax.Array,
     """Ragged chunked-prefill attention over the paged pool (DESIGN.md §3.10):
     q (Nt, H, D) — a packed ragged query block whose slot b owns rows
     ``[q_start[b], q_start[b] + q_len[b])`` (q_len ≤ ``chunk_cap``; 0 marks a
-    dead slot) — against the same (P, ps, Hkv, D) pools / (B, maxP) page
-    table / optional (P, ps, Hkv, 1) int8-KV scale pools as
-    ``paged_decode_attention`` → (Nt, H, D), rows no slot owns zeroed.
+    dead slot) — against the same stored pools / (B, maxP) page table /
+    optional int8-KV scale pools as ``paged_decode_attention`` → (Nt, H, D),
+    rows no slot owns zeroed.
 
     ``kv_len`` (B,) counts each slot's total visible tokens *after* this
     chunk's scatter, so the chunk spans absolute positions

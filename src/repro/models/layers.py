@@ -14,6 +14,7 @@ import jax.numpy as jnp
 
 from repro.core import qlinear as ql
 from repro.configs.base import ModelConfig
+from repro.kernels import paged_layout
 from repro.sharding import hints
 
 
@@ -210,32 +211,55 @@ def _scale_to_scores(scale: jax.Array) -> jax.Array:
 
 # --------------------------------------------------------------------- paged KV
 
-def _pool_flat(pool: jax.Array) -> jax.Array:
-    """(P, ps, Hkv, D|1) page pool → (P·ps, Hkv, D|1) flat-position view."""
-    return pool.reshape((pool.shape[0] * pool.shape[1],) + pool.shape[2:])
+def _pool_write(cache: dict, flat_idx: jax.Array, k: jax.Array,
+                v: jax.Array) -> dict:
+    """Write the new tokens' K/V rows ``k``/``v`` (N, Hkv, D) at flat page
+    positions ``flat_idx`` (N,) (page·ps + t) straight into the stored pools
+    (``kernels/paged_layout.py``): int8 codes plus per-token scale rows on an
+    int8-KV cache, the fp rows otherwise. Indices ≥ P·ps (sentinel page-table
+    entries, padded batch rows) are dropped — pages of other sequences are
+    never touched because the engine hands every live position exactly one
+    page slot. Returns the new pool dict, its placement pinned."""
+    ps, (Hkv, D) = cache["k_pages"].shape[1], k.shape[1:]
+    codes = lambda name, rows: paged_layout.write_codes(cache[name], flat_idx,
+                                                        rows)
+    if "k_scale_pages" in cache:
+        (kq, ks), (vq, vs) = kv_quantize(k), kv_quantize(v)
+        scales = lambda name, rows: paged_layout.write_scales(
+            cache[name], flat_idx, rows, ps)
+        new = {"k_pages": codes("k_pages", kq), "v_pages": codes("v_pages", vq),
+               "k_scale_pages": scales("k_scale_pages", ks),
+               "v_scale_pages": scales("v_scale_pages", vs)}
+    else:
+        new = {"k_pages": codes("k_pages", k), "v_pages": codes("v_pages", v)}
+    return {name: hints.constrain_kv_pages(
+                pool, Hkv, ps if name.endswith("scale_pages") else D)
+            for name, pool in new.items()}
 
 
-def _pool_scatter(pool: jax.Array, flat_idx: jax.Array, rows: jax.Array) -> jax.Array:
-    """Write ``rows`` (N, Hkv, D|1) at flat page positions ``flat_idx`` (N,) into
-    a (P, ps, Hkv, D|1) pool. Indices ≥ P·ps (sentinel page-table entries, padded
-    batch rows) are dropped — pages of other sequences are never touched because
-    the engine hands every live position exactly one page slot."""
-    flat = _pool_flat(pool).at[flat_idx].set(rows.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
-
-
-def _pool_gather(pool: jax.Array, page_table: jax.Array) -> jax.Array:
-    """Materialize the logical (B, max_pages·ps, Hkv, D|1) view of a pool through
-    the page table. Sentinel entries clamp to an arbitrary valid page — callers
-    mask those positions before the softmax. With ``max_pages·ps == max_len``
-    the result is positionally identical to a dense (B, T, ...) cache row.
+def _pool_gather(cache: dict, page_table: jax.Array, n_kv_heads: int):
+    """Materialize the logical (B, max_pages·ps, Hkv, D) f32 K and V of the
+    pools through the page table, int8 codes dequantized with their per-token
+    scales. Sentinel entries clamp to an arbitrary valid page — callers mask
+    those positions before the softmax. With ``max_pages·ps == max_len`` the
+    result is positionally identical to a dense (B, T, ...) cache row.
     Warm-prefix *prefill* only (``paged_prefill_attention`` reads the shared
     prefix back once per admission): decode never gathers — it runs the
     gather-free Pallas paged kernel on every path (DESIGN.md §3.8)."""
-    P, ps = pool.shape[0], pool.shape[1]
-    gidx = page_table[:, :, None] * ps + jnp.arange(ps)[None, None, :]
-    gidx = jnp.clip(gidx, 0, P * ps - 1).reshape(page_table.shape[0], -1)
-    return _pool_flat(pool)[gidx]
+    P, ps = cache["k_pages"].shape[:2]
+    B = page_table.shape[0]
+    tab = jnp.clip(page_table, 0, P - 1)
+
+    def logical(pages):                    # (B, maxP, ps, Hkv, D|1) → (B, T, ...)
+        return pages.reshape((B, -1) + pages.shape[3:]).astype(jnp.float32)
+
+    kf, vf = (logical(paged_layout.codes_view(cache[n][tab], n_kv_heads))
+              for n in ("k_pages", "v_pages"))
+    if "k_scale_pages" in cache:
+        kf, vf = (f * logical(paged_layout.scales_view(cache[n][tab],
+                                                       n_kv_heads, ps))
+                  for f, n in ((kf, "k_scale_pages"), (vf, "v_scale_pages")))
+    return kf, vf
 
 
 def paged_prefill_attention(
@@ -256,13 +280,7 @@ def paged_prefill_attention(
     B, S, H, D = q.shape
     Hkv = k_new.shape[2]
     G = H // Hkv
-    kv_int8 = "k_scale_pages" in cache
-
-    kf = _pool_gather(cache["k_pages"], page_table).astype(jnp.float32)
-    vf = _pool_gather(cache["v_pages"], page_table).astype(jnp.float32)
-    if kv_int8:
-        kf = kf * _pool_gather(cache["k_scale_pages"], page_table)
-        vf = vf * _pool_gather(cache["v_scale_pages"], page_table)
+    kf, vf = _pool_gather(cache, page_table, Hkv)
     T = kf.shape[1]
 
     pl_ = jnp.reshape(prefix_len, (-1,)).astype(jnp.int32)
@@ -399,17 +417,18 @@ def _paged_attention(q, k, v, cache: dict, page_table: Optional[jax.Array],
                      cur_len, prefix_len, window: Optional[int], decode: bool,
                      q_len=None):
     """Attention against a paged pool (DESIGN.md §3.8): scatter the new K/V
-    through the page table, then attend. Every decode path — fp pools and int8
-    codes + per-token scale pools alike, on all serving paths — runs the
-    gather-free Pallas paged kernel (``ops.paged_decode_attention``): the scale
-    tiles ride the same scalar-prefetched page indices as the code tiles and
+    through the page table into the pools' stored layout
+    (``kernels/paged_layout.py``), then attend on the pools as stored. Every
+    decode path — fp pools and int8 codes + per-token scale pools alike, on
+    all serving paths — runs the gather-free Pallas paged kernel
+    (``ops.paged_decode_attention``): the scale rows ride the same
+    scalar-prefetched page indices as the code tiles and
     dequantize in-kernel at the score/prob level, the dense
     ``decode_attention`` application points, so the dense (B, max_pages·ps, ...)
     view is never materialized at decode. Returns (out, new_cache)."""
     if page_table is None:
         raise ValueError("paged cache without a page_table")
     B, S = q.shape[0], q.shape[1]
-    kv_int8 = "k_scale_pages" in cache
     P, ps = cache["k_pages"].shape[0], cache["k_pages"].shape[1]
 
     if q_len is not None:
@@ -426,23 +445,7 @@ def _paged_attention(q, k, v, cache: dict, page_table: Optional[jax.Array],
             axis=1)
         flat = jnp.where(row_valid, entry * ps + abs_pos % ps, P * ps).reshape(-1)
         merge = lambda t: t.reshape((B * S,) + t.shape[2:])
-        if kv_int8:
-            kq, ks = kv_quantize(k)
-            vq, vs = kv_quantize(v)
-            new_cache = {
-                "k_pages": _pool_scatter(cache["k_pages"], flat, merge(kq)),
-                "v_pages": _pool_scatter(cache["v_pages"], flat, merge(vq)),
-                "k_scale_pages": _pool_scatter(cache["k_scale_pages"], flat,
-                                               merge(ks)),
-                "v_scale_pages": _pool_scatter(cache["v_scale_pages"], flat,
-                                               merge(vs)),
-            }
-        else:
-            new_cache = {
-                "k_pages": _pool_scatter(cache["k_pages"], flat, merge(k)),
-                "v_pages": _pool_scatter(cache["v_pages"], flat, merge(v)),
-            }
-        new_cache = {kk: hints.constrain_kv_pages(vv) for kk, vv in new_cache.items()}
+        new_cache = _pool_write(cache, flat, merge(k), merge(v))
         from repro.kernels import ops as kops
         out = kops.paged_verify_attention(
             q, new_cache["k_pages"], new_cache["v_pages"], page_table, cl, qln,
@@ -456,21 +459,7 @@ def _paged_attention(q, k, v, cache: dict, page_table: Optional[jax.Array],
         pos = jnp.clip(cl - 1, 0, page_table.shape[1] * ps - 1)
         entry = jnp.take_along_axis(page_table, (pos // ps)[:, None], axis=1)[:, 0]
         flat = entry * ps + pos % ps           # sentinel entry (==P) ⇒ dropped
-        if kv_int8:
-            kq, ks = kv_quantize(k)
-            vq, vs = kv_quantize(v)
-            new_cache = {
-                "k_pages": _pool_scatter(cache["k_pages"], flat, kq[:, 0]),
-                "v_pages": _pool_scatter(cache["v_pages"], flat, vq[:, 0]),
-                "k_scale_pages": _pool_scatter(cache["k_scale_pages"], flat, ks[:, 0]),
-                "v_scale_pages": _pool_scatter(cache["v_scale_pages"], flat, vs[:, 0]),
-            }
-        else:
-            new_cache = {
-                "k_pages": _pool_scatter(cache["k_pages"], flat, k[:, 0]),
-                "v_pages": _pool_scatter(cache["v_pages"], flat, v[:, 0]),
-            }
-        new_cache = {kk: hints.constrain_kv_pages(vv) for kk, vv in new_cache.items()}
+        new_cache = _pool_write(cache, flat, k[:, 0], v[:, 0])
         from repro.kernels import ops as kops
         out = kops.paged_decode_attention(
             q, new_cache["k_pages"], new_cache["v_pages"], page_table, cl,
@@ -491,21 +480,7 @@ def _paged_attention(q, k, v, cache: dict, page_table: Optional[jax.Array],
         page_table, jnp.clip(abs_pos // ps, 0, page_table.shape[1] - 1), axis=1)
     flat = jnp.where(row_valid, entry * ps + abs_pos % ps, P * ps).reshape(-1)
     merge = lambda t: t.reshape((B * S,) + t.shape[2:])
-    if kv_int8:
-        kq, ks = kv_quantize(k)
-        vq, vs = kv_quantize(v)
-        new_cache = {
-            "k_pages": _pool_scatter(cache["k_pages"], flat, merge(kq)),
-            "v_pages": _pool_scatter(cache["v_pages"], flat, merge(vq)),
-            "k_scale_pages": _pool_scatter(cache["k_scale_pages"], flat, merge(ks)),
-            "v_scale_pages": _pool_scatter(cache["v_scale_pages"], flat, merge(vs)),
-        }
-    else:
-        new_cache = {
-            "k_pages": _pool_scatter(cache["k_pages"], flat, merge(k)),
-            "v_pages": _pool_scatter(cache["v_pages"], flat, merge(v)),
-        }
-    new_cache = {kk: hints.constrain_kv_pages(vv) for kk, vv in new_cache.items()}
+    new_cache = _pool_write(cache, flat, merge(k), merge(v))
     if prefix_len is None:
         # cold admission: exactly the dense prefill attention (bitwise parity)
         out = _prefill_attention(q, k, v, cfg, ctx, window=window,
@@ -530,7 +505,6 @@ def _chunked_attention(q, k, v, cache: dict, page_table: jax.Array,
     Returns (out (1, Nt, H, D), new_cache)."""
     B_tab, maxP = page_table.shape
     Nt = q.shape[1]
-    kv_int8 = "k_scale_pages" in cache
     P, ps = cache["k_pages"].shape[0], cache["k_pages"].shape[1]
 
     pos = jnp.reshape(chunk["positions"], (-1,)).astype(jnp.int32)    # (Nt,)
@@ -539,21 +513,7 @@ def _chunked_attention(q, k, v, cache: dict, page_table: jax.Array,
     entry = page_table[jnp.clip(sid, 0, B_tab - 1),
                        jnp.clip(pos // ps, 0, maxP - 1)]
     flat = jnp.where(row_valid, entry * ps + pos % ps, P * ps)
-    if kv_int8:
-        kq, ks = kv_quantize(k)
-        vq, vs = kv_quantize(v)
-        new_cache = {
-            "k_pages": _pool_scatter(cache["k_pages"], flat, kq[0]),
-            "v_pages": _pool_scatter(cache["v_pages"], flat, vq[0]),
-            "k_scale_pages": _pool_scatter(cache["k_scale_pages"], flat, ks[0]),
-            "v_scale_pages": _pool_scatter(cache["v_scale_pages"], flat, vs[0]),
-        }
-    else:
-        new_cache = {
-            "k_pages": _pool_scatter(cache["k_pages"], flat, k[0]),
-            "v_pages": _pool_scatter(cache["v_pages"], flat, v[0]),
-        }
-    new_cache = {kk: hints.constrain_kv_pages(vv) for kk, vv in new_cache.items()}
+    new_cache = _pool_write(cache, flat, k[0], v[0])
     from repro.kernels import ops as kops
     out = kops.ragged_prefill_attention(
         q[0], k[0], v[0], new_cache["k_pages"], new_cache["v_pages"],

@@ -31,6 +31,7 @@ from typing import Callable, Dict, Optional
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
+from repro.kernels import paged_layout as L
 
 
 @dataclasses.dataclass(frozen=True)
@@ -64,16 +65,18 @@ def _attn_dense(cfg: ModelConfig, batch_size: int, max_len: int, dtype,
 
 def _attn_paged(cfg: ModelConfig, n_pages: int, page_size: int, dtype,
                 kv_int8: bool) -> dict:
-    pool = (n_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
+    """Per-layer K/V pools in the layout the paged kernels DMA from
+    (``kernels/paged_layout.py``): (P, ps, Hkv·D) pages, heads h-major in the
+    lane axis — int8 codes plus (P, 1, L) f32 per-token scale rows (lane
+    h·ps + t, padded to 128 lanes) on an int8-KV cache. Every writer scatters
+    into, and every reader reads, this layout as stored."""
+    codes = lambda dt: L.code_pool(n_pages, page_size, cfg.n_kv_heads,
+                                   cfg.head_dim, dt)
     if kv_int8:
-        return {
-            "k_pages": jnp.zeros(pool, jnp.int8),
-            "v_pages": jnp.zeros(pool, jnp.int8),
-            "k_scale_pages": jnp.zeros(pool[:3] + (1,), jnp.float32),
-            "v_scale_pages": jnp.zeros(pool[:3] + (1,), jnp.float32),
-        }
-    return {"k_pages": jnp.zeros(pool, dtype),
-            "v_pages": jnp.zeros(pool, dtype)}
+        scales = lambda: L.scale_pool(n_pages, page_size, cfg.n_kv_heads)
+        return {"k_pages": codes(jnp.int8), "v_pages": codes(jnp.int8),
+                "k_scale_pages": scales(), "v_scale_pages": scales()}
+    return {"k_pages": codes(dtype), "v_pages": codes(dtype)}
 
 
 def _ssm_conv_channels(cfg: ModelConfig) -> int:

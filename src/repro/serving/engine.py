@@ -48,6 +48,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig
 from repro.core import qlinear as ql
+from repro.kernels import paged_layout
 from repro.models import model as M, state as state_lib
 from repro.models.layers import QuantContext
 from repro.serving import drafter, paging, spans
@@ -240,17 +241,19 @@ def make_paged_admit_step(cfg: ModelConfig, quant: Optional[ql.QuantConfig] = No
 
 def _page_copy(caches: dict, src, dst, n_tok):
     """Copy-on-write of a partially shared tail page (DESIGN.md §3.8): duplicate
-    the first ``n_tok`` token rows of physical page ``src`` into the freshly
-    allocated ``dst`` across every layer's pools (codes and int8 scale pages
-    alike); rows ≥ n_tok stay zero, exactly as a cold prefill would leave them
-    before writing the suffix."""
-    def cp(leaf):                       # (n_blocks, P, ps, Hkv, D|1)
-        row = leaf[:, src]
-        mask = jnp.arange(leaf.shape[2])[None, :, None, None] < n_tok
-        return leaf.at[:, dst].set(jnp.where(mask, row, jnp.zeros_like(row)))
+    the first ``n_tok`` tokens of physical page ``src`` into the freshly
+    allocated ``dst`` across every layer's pools (codes and int8 scale rows
+    alike); tokens ≥ n_tok stay zero, exactly as a cold prefill would leave
+    them before writing the suffix."""
+    def cp(block):                      # leaves (n_blocks, P, ...)
+        if "k_pages" not in block:
+            return block
+        ps = block["k_pages"].shape[2]
+        return {name: paged_layout.copy_tokens(leaf, src, dst, n_tok, ps)
+                for name, leaf in block.items()}
 
     out = dict(caches)
-    out["blocks"] = jax.tree_util.tree_map(cp, caches["blocks"])
+    out["blocks"] = [cp(b) for b in caches["blocks"]]
     return out
 
 

@@ -20,6 +20,8 @@ from typing import Dict, Optional, Tuple
 import jax
 from jax.sharding import PartitionSpec as P
 
+from repro.kernels.paged_layout import splits_in_heads
+
 _EP_AXIS: contextvars.ContextVar[Optional[str]] = contextvars.ContextVar(
     "ep_axis", default=None)
 _DP_AXES: contextvars.ContextVar[Optional[Tuple[str, ...]]] = contextvars.ContextVar(
@@ -249,22 +251,27 @@ def constrain_kv_cache(x: jax.Array) -> jax.Array:
     return _constrain(x, P(*spec))
 
 
-def constrain_kv_pages(x: jax.Array) -> jax.Array:
-    """(P, ps, Hkv, D|1) paged-KV pool leaf (codes, fp pages or int8 per-token
-    scale pages — DESIGN.md §3.8) — pin the physical page axis to the data axes
-    and the kv-head axis to the model axis when they divide, mirroring
+def constrain_kv_pages(x: jax.Array, n_kv_heads: int,
+                       head_lanes: int) -> jax.Array:
+    """A paged-KV pool leaf in its stored layout (DESIGN.md §3.8,
+    ``kernels/paged_layout.py``): (P, ps, Hkv·D) codes or fp pages
+    (``head_lanes`` = D) or (P, 1, L) int8 per-token scale rows
+    (``head_lanes`` = ps). Pin the physical page axis to the data axes and
+    the lane axis to the model axis when it splits in whole kv heads (the
+    same heads per device in code and scale pools), mirroring
     planner.cache_shardings so the per-step page scatter keeps the pool's
     placement instead of GSPMD resharding the whole pool every decode step.
     The page table itself stays replicated (tiny, host-owned)."""
     dp = _DP_AXES.get()
     tp = _TP_AXIS.get()
-    if (dp is None and tp is None) or x.ndim < 4:
+    if dp is None and tp is None:
         return x
     spec = [None] * x.ndim
     if dp is not None and x.shape[0] % _axis_size(dp) == 0:
         spec[0] = dp
-    if tp is not None and x.shape[2] % _axis_size(tp) == 0:
-        spec[2] = tp
+    if tp is not None and splits_in_heads(x.shape[-1], n_kv_heads, head_lanes,
+                                          _axis_size(tp)):
+        spec[-1] = tp
     if all(s is None for s in spec):
         return x
     return _constrain(x, P(*spec))
@@ -274,9 +281,9 @@ def constrain_state_pages(x: jax.Array) -> jax.Array:
     """Paged SSM state pools (DESIGN.md §3.13): ``state_pages`` (P, H, Pd, N) or
     ``conv_pages`` (P, K-1, C) — pin the physical page axis to the data axes and,
     for the 4-d recurrent-state pool, the head axis to the model axis, mirroring
-    planner.cache_shardings. Deliberately NOT routed through constrain_kv_pages:
-    that helper pins dim 2 of any 4-d leaf (the kv-head axis of a KV pool), which
-    on a state pool would land on the head-*dim* axis instead of the head axis."""
+    planner.cache_shardings. Not routed through constrain_kv_pages: that helper
+    splits a KV pool's lane axis in whole kv heads, which a state pool does not
+    have."""
     dp = _DP_AXES.get()
     tp = _TP_AXIS.get()
     if (dp is None and tp is None) or x.ndim < 3:
@@ -335,9 +342,8 @@ def manual_kernel(fn, args: tuple, mesh=None):
     ``args`` may carry ``None`` leaves for optional operands — e.g. the paged
     decode kernel's int8-KV per-token scale pools (DESIGN.md §3.8), absent on
     fp pools: the per-leaf ``tree_map`` leaves them un-spec'd, so one boundary
-    serves both the fp and int8-KV operand tuples (any operand relayout, like
-    the scale pools' (P, ps, Hkv, 1)→(P, Hkv, ps) transpose, belongs *inside*
-    ``fn`` where the partitioner cannot touch it)."""
+    serves both the fp and int8-KV operand tuples (any operand relayout
+    belongs *inside* ``fn`` where the partitioner cannot touch it)."""
     if mesh is None:
         return fn(*args)
     replicated = jax.tree_util.tree_map(lambda _: P(), args)

@@ -27,6 +27,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ShapeConfig
+from repro.kernels.paged_layout import splits_in_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -316,16 +317,24 @@ def cache_shardings(cache_tree, cfg: ModelConfig, plan: Plan, mesh: Mesh):
     int8 KV per-token scale leaves (``k_scale``/``v_scale``, (B,T,Hkv,1)) carry the
     same (B→dp, T→model) split as the codes they dequantize — a slot's scale row
     must live with its code row or every decode-step scatter pays a reshard.
-    Paged pools (``*_pages``, (P,ps,Hkv,D|1) — DESIGN.md §3.8): physical page
-    axis→dp, kv heads→model when divisible (there is no contiguous T axis to
-    sequence-shard; capacity scales with the dp-split page axis instead), with
-    the int8 scale pools following their code pools; the ``page_table`` and any
-    unrecognized leaf replicate. These placements govern *storage*: at the
+    Paged pools in their stored layout (``*_pages``: (P, ps, Hkv·D) codes,
+    (P, 1, L) int8 scale rows — DESIGN.md §3.8, ``kernels/paged_layout.py``):
+    physical page axis→dp, the lane axis→model when it splits in whole kv
+    heads (there is no contiguous T axis to sequence-shard; capacity scales
+    with the dp-split page axis instead), with the int8 scale pools following
+    their code pools' heads where their row splits the same way (a row padded
+    past its heads stays whole); the ``page_table`` and any unrecognized leaf
+    replicate. These placements govern *storage*: at the
     decode step the paged kernel consumes code and scale pools alike as
     operands of one ``hints.manual_kernel`` region (gathered at that boundary),
     so scale pools sharding differently from their codes would only add a
     reshard — following the code pools keeps scatter and gather symmetric.
     SSM caches: B→dp, heads→model when divisible."""
+    leaves = jax.tree_util.tree_flatten_with_path(cache_tree)[0]
+    # every attention pool of one cache shares the page size (codes' axis -2)
+    ps = next((leaf.shape[-2] for path, leaf in leaves
+               if _path_str(path).endswith("k_pages")), None)
+
     def one(path, leaf):
         pathstr = _path_str(path)
         names = pathstr.split("/")
@@ -337,8 +346,10 @@ def cache_shardings(cache_tree, cfg: ModelConfig, plan: Plan, mesh: Mesh):
         if last in ("k_pages", "v_pages", "k_scale_pages", "v_scale_pages"):
             if _maybe(plan.dp_axes, leaf.shape[off + 0], mesh):
                 spec[off + 0] = plan.dp_axes
-            if _maybe(plan.tp_axis, leaf.shape[off + 2], mesh):
-                spec[off + 2] = plan.tp_axis
+            head_lanes = ps if "scale" in last else cfg.head_dim
+            if splits_in_heads(leaf.shape[-1], cfg.n_kv_heads, head_lanes,
+                               _axis_size(mesh, plan.tp_axis)):
+                spec[-1] = plan.tp_axis
         elif last in ("k", "v", "k_scale", "v_scale"):
             if _maybe(plan.dp_axes, leaf.shape[off + 0], mesh):
                 spec[off + 0] = plan.dp_axes

@@ -28,6 +28,7 @@ import pytest
 from repro.configs import get
 from repro.core import qlinear as ql
 from repro.kernels import ops as kops
+from repro.kernels import paged_layout
 from repro.kernels import ref as kref
 from repro.kernels.flash_attention import (paged_decode_attention_pallas,
                                            ragged_prefill_attention_pallas)
@@ -115,9 +116,10 @@ def _kernel_vs_oracle(rng, B, Hkv, G, D, P, ps, maxP, C, kv_int8, *,
         tab = tab.at[sentinel_row].set(P)
     q = jnp.asarray(rng.standard_normal((max(Nt, 1), Hkv * G, D)), jnp.float32)
     kn, vn = _packed_new(rng, Nt, Hkv, D)
+    skp, svp, sks, svs = paged_layout.stored(kp, vp, ksp, vsp)
     got = kops.ragged_prefill_attention(
-        q, kn, vn, kp, vp, tab, qs, qln, kvl, chunk_cap=C,
-        k_scale_pages=ksp, v_scale_pages=vsp, window=window, softcap=softcap)
+        q, kn, vn, skp, svp, tab, qs, qln, kvl, chunk_cap=C,
+        k_scale_pages=sks, v_scale_pages=svs, window=window, softcap=softcap)
     qg = q.reshape(max(Nt, 1), Hkv, G, D)
     ref = kref.ragged_prefill_attention_ref(
         qg, kn, vn, kp, vp, tab, qs, qln, kvl, chunk_cap=C,
@@ -228,16 +230,13 @@ class TestRaggedKernelVsOracle:
             rows_v.append(vf)
         kn = jnp.asarray(np.stack(rows_k))
         vn = jnp.asarray(np.stack(rows_v))
+        skp, svp, sks, svs = paged_layout.stored(kp, vp, ksp, vsp)
         got = kops.ragged_prefill_attention(
-            q, kn, vn, kp, vp, tab, qs, qln, kvl, chunk_cap=4,
-            k_scale_pages=ksp, v_scale_pages=vsp)
+            q, kn, vn, skp, svp, tab, qs, qln, kvl, chunk_cap=4,
+            k_scale_pages=sks, v_scale_pages=svs)
         qd = q.reshape(B, Hkv, G, D)
-        ks = vs = None
-        if kv_int8:
-            ks = jnp.transpose(ksp[..., 0], (0, 2, 1))
-            vs = jnp.transpose(vsp[..., 0], (0, 2, 1))
-        dec = paged_decode_attention_pallas(qd, kp, vp, tab, kvl,
-                                            k_scale=ks, v_scale=vs,
+        dec = paged_decode_attention_pallas(qd, skp, svp, tab, kvl,
+                                            k_scale=sks, v_scale=svs,
                                             interpret=True)
         np.testing.assert_allclose(
             np.asarray(got).reshape(B, Hkv, G, D), np.asarray(dec),

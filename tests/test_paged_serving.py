@@ -28,6 +28,7 @@ import pytest
 from repro.configs import get
 from repro.core import qlinear as ql
 from repro.kernels import ops as kops
+from repro.kernels import paged_layout
 from repro.kernels import ref as kref
 from repro.models import model as M
 from repro.models.quantize import quantize_tree
@@ -224,6 +225,34 @@ class TestPrefixReuse:
         assert got[1] == cold[1]
         eng.pool.check()
 
+    def test_page_copy_moves_token_prefix_of_codes_and_scale_rows(self, small):
+        """``_page_copy`` on an int8-KV cache in the stored layout: in every
+        layer's code pools and scale rows, page ``dst`` reads (through the
+        logical view) the first ``n_tok`` tokens of page ``src`` and zeros
+        after them; every other page is untouched."""
+        cfg = small[0]
+        caches = M.init_cache(cfg, 2, T, kv_int8=True, layout="paged",
+                              page_size=PS)
+        rng = np.random.default_rng(9)
+        caches["blocks"] = [
+            {name: jnp.asarray(rng.integers(-127, 128, leaf.shape), leaf.dtype)
+             if leaf.dtype == jnp.int8 else
+             jnp.asarray(rng.random(leaf.shape), leaf.dtype)
+             for name, leaf in blk.items()} for blk in caches["blocks"]]
+        src, dst, n_tok = 1, 4, 3
+        out = E._page_copy(caches, src, dst, n_tok)
+        Hkv = cfg.n_kv_heads
+        view = {"k_pages": lambda p: paged_layout.codes_view(p, Hkv),
+                "k_scale_pages": lambda p: paged_layout.scales_view(p, Hkv, PS)}
+        for before, after in zip(caches["blocks"], out["blocks"]):
+            for name in before:
+                v = view[name.replace("v_", "k_")]
+                a, b = np.asarray(v(after[name])), np.asarray(v(before[name]))
+                np.testing.assert_array_equal(a[:, dst, :n_tok], b[:, src, :n_tok])
+                assert not a[:, dst, n_tok:].any(), name
+                keep = np.arange(a.shape[1]) != dst
+                np.testing.assert_array_equal(a[:, keep], b[:, keep])
+
 
 class TestAllocatorInvariants:
     def test_pool_basics(self):
@@ -401,9 +430,11 @@ class TestPagedKernelVsOracle:
         q = jnp.asarray(rng.standard_normal((B, 1, H, D)), jnp.float32)
         kp, vp, ks, vs = _rand_pools(rng, P, ps, Hkv, D, kv_int8)
         tab, kvl = _rand_table(rng, B, P, ps, maxP)
+        skp, svp, sks, svs = paged_layout.stored(kp, vp, ks, vs)
         for window, softcap in ((None, None), (5, None), (None, 30.0)):
-            got = kops.paged_decode_attention(q, kp, vp, tab, kvl,
-                                              k_scale_pages=ks, v_scale_pages=vs,
+            got = kops.paged_decode_attention(q, skp, svp, tab, kvl,
+                                              k_scale_pages=sks,
+                                              v_scale_pages=svs,
                                               window=window, softcap=softcap)
             want = kref.paged_decode_attention_ref(
                 q.reshape(B, Hkv, G, D), kp, vp, tab, kvl,
@@ -420,7 +451,8 @@ class TestPagedKernelVsOracle:
         rng = np.random.default_rng(31 + kv_int8)
         B, Hkv, G, D, P, ps, maxP = 2, 2, 2, 16, 8, 8, 4
         q = jnp.asarray(rng.standard_normal((B, 1, Hkv * G, D)), jnp.float32)
-        kp, vp, ks, vs = _rand_pools(rng, P, ps, Hkv, D, kv_int8)
+        kp, vp, ks, vs = paged_layout.stored(
+            *_rand_pools(rng, P, ps, Hkv, D, kv_int8))
         tab = jnp.asarray([[0, 1, 2, P], [3, 4, 5, P]], jnp.int32)
         kw = dict(k_scale_pages=ks, v_scale_pages=vs)
         got_scalar = kops.paged_decode_attention(
@@ -442,17 +474,19 @@ class TestPagedKernelVsOracle:
         kp, vp, ks, vs = _rand_pools(rng, P, ps, Hkv, D, kv_int8)
         tab = jnp.asarray([[5] + [P] * (maxP - 1), [P] * maxP], jnp.int32)
         kvl = jnp.asarray([3, 1], jnp.int32)   # free slots decode with cur_len 1
-        kw = dict(k_scale_pages=ks, v_scale_pages=vs)
-        got = kops.paged_decode_attention(q, kp, vp, tab, kvl, **kw)
+        skp, svp, sks, svs = paged_layout.stored(kp, vp, ks, vs)
+        kw = dict(k_scale_pages=sks, v_scale_pages=svs)
+        got = kops.paged_decode_attention(q, skp, svp, tab, kvl, **kw)
         want = kref.paged_decode_attention_ref(
-            q.reshape(B, Hkv, G, D), kp, vp, tab, kvl, **kw)
+            q.reshape(B, Hkv, G, D), kp, vp, tab, kvl,
+            k_scale_pages=ks, v_scale_pages=vs)
         np.testing.assert_allclose(np.asarray(got[0]),
                                    np.asarray(want.reshape(B, 1, Hkv * G, D)[0]),
                                    rtol=2e-5, atol=2e-5)
         assert np.isfinite(np.asarray(got)).all()
         # the live row's result is independent of the free row's garbage
-        got_solo = kops.paged_decode_attention(q[:1], kp, vp, tab[:1], kvl[:1],
-                                               **kw)
+        got_solo = kops.paged_decode_attention(q[:1], skp, svp, tab[:1],
+                                               kvl[:1], **kw)
         np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(got_solo[0]))
 
 

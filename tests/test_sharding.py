@@ -236,6 +236,75 @@ class TestQuantizedServingSpecs:
         assert blk["v_scale"].spec[:3] == blk["v"].spec[:3]
 
 
+class TestPagedPoolShardings:
+    def test_kv_head_lanes_shard_in_whole_heads_subprocess(self):
+        """The stored pool layout ((P, ps, Hkv·D) codes, (P, 1, L) scale rows,
+        DESIGN.md §3.8) on a CPU mesh: ``planner.cache_shardings`` and
+        ``hints.constrain_kv_pages`` put the lane axis on the model axis when
+        Hkv divides it, each device holding whole kv heads, the same heads in
+        the code and scale pools; a scale row padded past its heads, or an
+        Hkv the model axis does not divide, keeps its lanes whole."""
+        code = textwrap.dedent("""
+            import os
+            os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+            os.environ["JAX_PLATFORMS"] = "cpu"
+            import jax, jax.numpy as jnp
+            from jax.sharding import PartitionSpec as P
+            from repro.configs import get
+            from repro.launch.mesh import make_debug_mesh
+            from repro.models import model as M
+            from repro.sharding import hints, planner
+
+            cfg = get("starcoder2-7b", smoke=True)          # Hkv 2, D 16
+            Hkv, D = cfg.n_kv_heads, cfg.head_dim
+            assert Hkv == 2, Hkv
+
+            def lane_slices(sh, shape):
+                return {(s[-1].start or 0, s[-1].stop or shape[-1])
+                        for s in sh.devices_indices_map(shape).values()}
+
+            for (data, model), ps, split, scale_split in (
+                    ((2, 2), 8, True, False),    # Hkv·ps = 16: padded row
+                    ((2, 2), 64, True, True),    # Hkv·ps = 128: no padding
+                    ((1, 4), 64, False, False)): # 4 does not divide Hkv 2
+                mesh = make_debug_mesh(data, model)
+                plan = planner.make_serve_plan(cfg, mesh)
+                caches = jax.eval_shape(lambda: M.init_cache(
+                    cfg, 2, 128, kv_int8=True, layout="paged", page_size=ps))
+                sh = planner.cache_shardings(caches, cfg, plan, mesh)["blocks"][0]
+                blk = caches["blocks"][0]
+                tp = "model" if split else None
+                dp = plan.dp_axes                # the page axis always divides
+                assert sh["k_pages"].spec == P(None, dp, None, tp), sh["k_pages"]
+                if split:     # whole heads: device lanes [h·D, (h+1)·D)
+                    assert lane_slices(sh["k_pages"], blk["k_pages"].shape) == {
+                        (h * D, (h + 1) * D) for h in range(Hkv)}
+                want = P(None, dp, None, "model" if scale_split else None)
+                assert sh["k_scale_pages"].spec == want, (ps, sh["k_scale_pages"])
+                if scale_split:   # the same heads: lanes [h·ps, (h+1)·ps)
+                    assert lane_slices(sh["k_scale_pages"],
+                                       blk["k_scale_pages"].shape) == {
+                        (h * ps, (h + 1) * ps) for h in range(Hkv)}
+
+                # the in-step constraint pins what the planner stores
+                def pin(x, head_lanes):
+                    with hints.sharding_hints(dp_axes=plan.dp_axes,
+                                              tp_axis=plan.tp_axis, mesh=mesh):
+                        return hints.constrain_kv_pages(x, Hkv, head_lanes)
+                for name, head_lanes in (("k_pages", D), ("k_scale_pages", ps)):
+                    leaf = blk[name]
+                    x = jnp.zeros(leaf.shape[1:], leaf.dtype)
+                    got = jax.jit(lambda x: pin(x, head_lanes))(x).sharding
+                    want = jax.sharding.NamedSharding(mesh, P(*sh[name].spec[1:]))
+                    assert got.is_equivalent_to(want, x.ndim), (name, got.spec)
+            print("POOL-SHARDING-OK")
+        """)
+        r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                           text=True, timeout=600,
+                           env={**os.environ, "PYTHONPATH": SRC})
+        assert "POOL-SHARDING-OK" in r.stdout, r.stderr[-3000:]
+
+
 class TestDebugMesh:
     def test_make_debug_mesh_raises_with_device_count_hint(self):
         """A short host must raise with the XLA_FLAGS hint (like

@@ -28,6 +28,7 @@ import pytest
 from repro.configs import get
 from repro.core import qlinear as ql
 from repro.kernels import ops as kops
+from repro.kernels import paged_layout
 from repro.kernels import ref as kref
 from repro.kernels.flash_attention import paged_decode_attention_pallas
 from repro.models import model as M
@@ -268,8 +269,9 @@ class TestVerifyKernelVsOracle:
         tab, kvl = _rand_table(rng, B, P, ps, maxP)
         qln = _rand_qlen(rng, kvl, W)
         q = jnp.asarray(rng.standard_normal((B, W, Hkv * G, D)), jnp.float32)
-        got = kops.paged_verify_attention(q, kp, vp, tab, kvl, qln,
-                                          k_scale_pages=ksp, v_scale_pages=vsp)
+        skp, svp, sks, svs = paged_layout.stored(kp, vp, ksp, vsp)
+        got = kops.paged_verify_attention(q, skp, svp, tab, kvl, qln,
+                                          k_scale_pages=sks, v_scale_pages=svs)
         qg = jnp.transpose(q.reshape(B, W, Hkv, G, D), (0, 2, 1, 3, 4))
         ref = jnp.transpose(
             kref.paged_verify_attention_ref(qg, kp, vp, tab, kvl, qln,
@@ -290,8 +292,9 @@ class TestVerifyKernelVsOracle:
         tab, kvl = _rand_table(rng, B, P, ps, maxP)
         qln = _rand_qlen(rng, kvl, W)
         q = jnp.asarray(rng.standard_normal((B, W, Hkv * G, D)), jnp.float32)
-        got = kops.paged_verify_attention(q, kp, vp, tab, kvl, qln,
-                                          k_scale_pages=ksp, v_scale_pages=vsp,
+        skp, svp, sks, svs = paged_layout.stored(kp, vp, ksp, vsp)
+        got = kops.paged_verify_attention(q, skp, svp, tab, kvl, qln,
+                                          k_scale_pages=sks, v_scale_pages=svs,
                                           window=window, softcap=softcap)
         qg = jnp.transpose(q.reshape(B, W, Hkv, G, D), (0, 2, 1, 3, 4))
         ref = jnp.transpose(
@@ -310,7 +313,8 @@ class TestVerifyKernelVsOracle:
         speculate=1 path and all existing decode parity results carry over."""
         B, Hkv, G, D, P, ps, maxP = 2, 2, 2, 16, 8, 8, 4
         rng = np.random.default_rng(3)
-        kp, vp, ksp, vsp = _rand_pools(rng, P, ps, Hkv, D, kv_int8)
+        kp, vp, ksp, vsp = paged_layout.stored(
+            *_rand_pools(rng, P, ps, Hkv, D, kv_int8))
         tab, kvl = _rand_table(rng, B, P, ps, maxP)
         q = jnp.asarray(rng.standard_normal((B, 1, Hkv * G, D)), jnp.float32)
         dec = kops.paged_decode_attention(q, kp, vp, tab, kvl,
@@ -325,7 +329,8 @@ class TestVerifyKernelVsOracle:
         positions — bitwise equal to the decode kernel's output."""
         B, Hkv, G, D, P, ps, maxP, W = 2, 2, 2, 16, 8, 8, 4, 3
         rng = np.random.default_rng(4)
-        kp, vp, _, _ = _rand_pools(rng, P, ps, Hkv, D, False)
+        kp, vp, _, _ = paged_layout.stored(
+            *_rand_pools(rng, P, ps, Hkv, D, False))
         tab, kvl = _rand_table(rng, B, P, ps, maxP)
         q1 = jnp.asarray(rng.standard_normal((B, Hkv, G, D)), jnp.float32)
         qw = jnp.concatenate(
@@ -352,8 +357,9 @@ class TestVerifyKernelVsOracle:
         kvl = kvl.at[1].set(1)
         qln = jnp.asarray([W, 1], jnp.int32)
         q = jnp.asarray(rng.standard_normal((B, W, Hkv * G, D)), jnp.float32)
-        out = kops.paged_verify_attention(q, kp, vp, tab, kvl, qln,
-                                          k_scale_pages=ksp, v_scale_pages=vsp)
+        skp, svp, sks, svs = paged_layout.stored(kp, vp, ksp, vsp)
+        out = kops.paged_verify_attention(q, skp, svp, tab, kvl, qln,
+                                          k_scale_pages=sks, v_scale_pages=svs)
         assert np.isfinite(np.asarray(out)).all()
         # row 0 untouched by row 1's sentinels
         qg = jnp.transpose(q.reshape(B, W, Hkv, G, D), (0, 2, 1, 3, 4))

@@ -16,7 +16,9 @@ The topology is described inside a module fixture, never at import: only one
 process may load the TPU compiler library at a time, and under several pytest
 workers the one given this file must be the only one that tries.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +27,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import act_quantize as aq
 from repro.kernels import flash_attention as fa
+from repro.kernels import paged_layout
 from repro.kernels import qgemm as qg
+from repro.models import layers
 
 # kv heads, group, head_dim, d_model, and N of the hot GEMM (expert up / MLP up)
 WIDTHS = [
@@ -69,10 +73,12 @@ def _compile(fn, one_chip, *shapes):
 
 
 def _pools(w, kv_int8):
+    """The pools' stored layout (kernels/paged_layout.py)."""
     code = jnp.int8 if kv_int8 else jnp.bfloat16
-    pools = [((P, PS, w["Hkv"], w["D"]), code)] * 2
+    pools = [((P, PS, w["Hkv"] * w["D"]), code)] * 2
     if kv_int8:
-        pools += [((P, w["Hkv"], PS), jnp.float32)] * 2
+        pools += [((P, 1, paged_layout.scale_width(w["Hkv"], PS)),
+                   jnp.float32)] * 2
     return pools
 
 
@@ -147,3 +153,42 @@ def test_ragged_prefill(one_chip, w, kv_int8):
              ((N, Hkv, D), jnp.bfloat16), ((N, Hkv, D), jnp.bfloat16),
              *pools[:2], ((B, MAXP), jnp.int32), ((B,), jnp.int32),
              ((B,), jnp.int32), ((B,), jnp.int32), *pools[2:])
+
+
+@pytest.mark.parametrize("kv_int8", [False, True], ids=["bf16_kv", "int8_kv"])
+@pytest.mark.parametrize("w", WIDTHS)
+def test_decode_layer_keeps_pool_layout(one_chip, w, kv_int8):
+    """One layer's pool write plus paged decode call, as the decode step makes
+    them (``layers._pool_write``, then the kernel, the pools donated as the
+    engine donates them), compiles with no ``copy``, ``transpose`` or
+    ``reshape`` the size of a whole pool: the kernel reads the pools as they
+    are stored, so no call converts one."""
+    Hkv, G, D = w["Hkv"], w["G"], w["D"]
+    names = ["k_pages", "v_pages"] + (["k_scale_pages", "v_scale_pages"]
+                                      if kv_int8 else [])
+    pools = dict(zip(names, _pools(w, kv_int8)))
+
+    def fn(q, k, v, pools, tab, kvl):
+        pos = kvl - 1                    # the decode step's scatter position
+        entry = jnp.take_along_axis(tab, (pos // PS)[:, None], axis=1)[:, 0]
+        new = layers._pool_write(pools, entry * PS + pos % PS, k, v)
+        out = fa.paged_decode_attention_pallas(
+            q, new["k_pages"], new["v_pages"], tab, kvl,
+            k_scale=new.get("k_scale_pages"), v_scale=new.get("v_scale_pages"))
+        return out, new
+
+    sds = lambda s, dt: jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+    args = [sds((B, Hkv, G, D), jnp.bfloat16), sds((B, Hkv, D), jnp.bfloat16),
+            sds((B, Hkv, D), jnp.bfloat16),
+            {n: sds(*pools[n]) for n in names},
+            sds((B, MAXP), jnp.int32), sds((B,), jnp.int32)]
+    text = jax.jit(fn, donate_argnums=3).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text, "the kernel did not lower to Mosaic"
+    # element counts of a whole pool: stored codes and scale rows, and the
+    # logical (P, ps, Hkv, 1) scales a per-call relayout would build
+    whole = {math.prod(shape) for shape, _ in pools.values()} | {P * PS * Hkv}
+    moved = [line.strip()[:160] for line in text.splitlines()
+             if (m := re.search(r"= [a-z0-9]+\[([0-9,]*)\]\S* "
+                                r"(copy|transpose|reshape)\(", line))
+             and math.prod(int(d) for d in m.group(1).split(",") if d) in whole]
+    assert not moved, moved
