@@ -48,39 +48,38 @@ def paged_decode(kv_lens, n_heads: int, n_kv_heads: int, head_dim: int) -> Work:
                 + rows * n_heads * head_dim * 2 * 2 + rows * 4)
 
 
-def linear_params(c: dict) -> tuple:
-    """(int8 linear weights a token passes through in one block, router
-    weights in one block)."""
-    d, D = c["d_model"], c["head_dim"]
-    hq, hkv = c["n_heads"] * D, c["n_kv_heads"] * D
-    attn = d * (hq + 2 * hkv) + hq * d
-    mats = 3 if c["act"].endswith("_glu") else 2
-    if c.get("n_experts"):
-        return attn + c["top_k"] * mats * d * c["d_ff_expert"], d * c["n_experts"]
-    return attn + mats * d * c["d_ff"], 0
+@dataclasses.dataclass(frozen=True)
+class ModelWork:
+    """What one token costs a model, as its module's ``work(c)`` gives it:
+    int8 and bf16 operations that do not depend on the context, bf16
+    operations per attended position (attention's QK and PV over every layer
+    that attends), and the head's operations for one sampled row."""
+    int8_ops: float
+    bf16_ops: float
+    bf16_ops_per_position: float
+    head_ops: float
 
 
-def token_work(c: dict, ctx: int) -> Work:
-    """One token through the model, attending to ``ctx`` positions (itself
-    included): int8 linears in every block; bf16 attention (QK and PV), router
-    and the head."""
-    lin, router = linear_params(c)
-    L = c["n_layers"]
-    attn = 4.0 * c["n_heads"] * c["head_dim"] * ctx
-    return Work(int8_ops=2.0 * lin * L,
-                bf16_ops=L * (attn + 2.0 * router) + 2.0 * c["d_model"] * c["vocab"])
+def token_work(m: ModelWork, ctx: int) -> Work:
+    """One decoded token, attending to ``ctx`` positions (itself included)."""
+    return Work(int8_ops=m.int8_ops,
+                bf16_ops=m.bf16_ops_per_position * ctx + m.bf16_ops + m.head_ops)
 
 
-def prefill_work(c: dict, prompt: int, reused: int) -> Work:
+def prefill_work(m: ModelWork, prompt: int, reused: int) -> Work:
     """The prompt tokens a request computes (those after the ``reused`` prefix
     served from cache), each attending to every earlier position. The head
     runs once, for the last token."""
     n = prompt - reused
     if n <= 0:
         return Work()
-    lin, router = linear_params(c)
-    L = c["n_layers"]
     ctx_sum = n * reused + n * (n + 1) / 2.0
-    return Work(int8_ops=2.0 * lin * L * n,
-                bf16_ops=L * (4.0 * c["n_heads"] * c["head_dim"] * ctx_sum
-                              + 2.0 * router * n) + 2.0 * c["d_model"] * c["vocab"])
+    return Work(int8_ops=m.int8_ops * n,
+                bf16_ops=m.bf16_ops_per_position * ctx_sum + m.bf16_ops * n + m.head_ops)
+
+
+def prompt_rows_work(m: ModelWork, rows: float, ctx: float) -> Work:
+    """``rows`` prompt tokens computed in packed chunks, each attending to
+    ``ctx`` positions; no head (a prompt's head runs with its first token)."""
+    return Work(int8_ops=m.int8_ops * rows,
+                bf16_ops=(m.bf16_ops_per_position * ctx + m.bf16_ops) * rows)

@@ -3,8 +3,9 @@
 The configuration file names the program's registry arch and gives every size
 it runs with; keys that differ from the registry entry (a cut in depth) replace
 it. Weights come from ``weights.make`` (the benchmark's own draw from the
-seed); calibration and post-training quantization to the int8 tree are the
-program's own, on the device, after which the bfloat16 tree is dropped.
+seed, in the layout of the configuration's model module); calibration and
+post-training quantization to the int8 tree are the program's own, on the
+device, after which the bfloat16 tree is dropped.
 """
 from __future__ import annotations
 
@@ -24,9 +25,9 @@ def program_config(c: dict):
     return dataclasses.replace(base, **over)
 
 
-def build(c: dict, seed: int, marks: dict, clock):
-    """The int8 tree for ``seed``; ``marks`` receives the seconds (and compile
-    seconds) of each part."""
+def build(c: dict, layout: dict, seed: int, marks: dict, clock):
+    """The int8 tree for ``seed``, drawn in ``layout`` (the model module's);
+    ``marks`` receives the seconds (and compile seconds) of each part."""
     import time
 
     import jax
@@ -39,7 +40,7 @@ def build(c: dict, seed: int, marks: dict, clock):
 
     cfg = program_config(c)
     t, k = time.monotonic(), clock.total
-    params = weights.make(c, seed)
+    params = weights.make(layout, seed)
     jax.block_until_ready(params)
     marks["init_s"], marks["init_compile_s"] = time.monotonic() - t, clock.total - k
     t, k = time.monotonic(), clock.total

@@ -1,12 +1,17 @@
 """One run of one cell: build, warm up, measure a window, check, report.
 
 The window drives ``AsyncServer.submit`` with one replica per chip of the
-cell, on the traffic's ``EngineConfig`` and the fused-int8 paged path. Set-up
-(``setup_s``) is everything from process start to the window: weights,
-calibration, quantization, server start and warm-up of exactly the shapes the
-traffic uses. After the window the program's state is freed and the plain
-reference runs over a sample, drawn from the seed, of the requests the window
-served, those still in flight at its close with the tokens they were served.
+cell, behind the server's prefix-affinity router, on the traffic's
+``EngineConfig`` and the fused-int8 paged path. Set-up (``setup_s``) is
+everything from process start to the window: weights, calibration,
+quantization, server start, warm-up of exactly the shapes the traffic uses on
+every replica, and for sessions the conversations' history and their opening
+turns' prefills (``client.drive``). Engine counters are summed over the
+replicas and, with the server's and the router's, taken as their change over
+the window. After the window the program's state
+is freed and the plain reference (the configuration's model module) runs over
+a sample, drawn from the seed, of the requests the window served on any
+replica, those still in flight at its close with the tokens they were served.
 """
 from __future__ import annotations
 
@@ -24,8 +29,8 @@ from typing import List, Optional
 import numpy as np
 
 from bench.harness import client as C
-from bench.harness import reference, spec, trace as T, weights
-from bench.harness.traffic import Schedule, warmup_prompt
+from bench.harness import spec, trace as T, weights
+from bench.harness.traffic import schedule, warmup_prompt
 
 TRACE_AT = 0.4         # the traced sub-window opens at this share of the window
 TRACE_MAX_S = 3.0      # and lasts at most this long
@@ -66,9 +71,11 @@ class Run:
     recs: List[C.Record]
     window: C.Window
     setup_s: float
-    counters: dict                 # engine counters: delta over the window
+    counters: dict                 # engine counters, summed over replicas: delta over the window
+    server: dict = dataclasses.field(default_factory=dict)  # server and router counters: delta
     trace: Optional[T.Trace] = None
     trace_window: Optional[C.Window] = None
+    trace_counters: dict = dataclasses.field(default_factory=dict)  # engine: delta over the trace
     peaks: object = None
 
 
@@ -80,51 +87,91 @@ def _delta(a: dict, b: dict) -> dict:
     return {k: b[k] - a.get(k, 0) for k in b if isinstance(b[k], (int, float))}
 
 
+def engine_counters(srv) -> dict:
+    """Every replica's engine counters, summed (a replica being rebuilt after
+    a failure has no engine and adds nothing)."""
+    out: dict = {}
+    for r in srv.replicas:
+        for k, v in (dict(r.engine.counters) if r.engine is not None else {}).items():
+            out[k] = out.get(k, 0) + v
+    return out
+
+
+def server_counters(srv) -> dict:
+    """The server's counters with the router's affinity hits."""
+    return dict(srv.counters, affinity_hits=srv.router.affinity_hits)
+
+
 async def _burst(srv, request_cls, prompts, max_new):
-    """Queue every prompt before the engine looks, so it admits them in one
-    batch; wait for all to finish."""
-    async def one(p):
-        async for ev in srv.submit(request_cls(prompt=p.tolist(), max_new=max_new)):
+    """Queue every prompt, ``(replica, tokens)``, before the engines look, so
+    each replica admits its share in one batch; wait for all to finish."""
+    async def one(r, p):
+        req = request_cls(prompt=p.tolist(), max_new=max_new, replica_hint=r)
+        async for ev in srv.submit(req):
             if ev.kind == "error":
                 raise RuntimeError(f"warm-up request failed: {ev.error}")
 
     target = srv.counters["submitted"] + len(prompts)
     srv.pause()
-    tasks = [asyncio.ensure_future(one(p)) for p in prompts]
+    tasks = [asyncio.ensure_future(one(r, p)) for r, p in prompts]
     while srv.counters["submitted"] < target:
         await asyncio.sleep(0.002)
     srv.resume()
     await asyncio.gather(*tasks)
 
 
-async def _warm_up(srv, request_cls, sched: Schedule, vocab: int):
-    """Compile every program shape the traffic will use, through the server.
+async def _warm_up(srv, request_cls, sched, vocab: int):
+    """Compile every program shape the traffic will use, on every replica,
+    through the server (``replica_hint``; the replicas compile side by side).
     A chunked engine runs one packed step shape. Otherwise each admission is a
     prefill of (rows, bucket): rows a power of two up to the batch, bucket
     the engine's padded length of each prompt length the mix holds, each
-    with the caches already on the chip; then the decode step."""
+    with the caches already on the chip; then the decode step. Every prompt
+    here opens with a first token that no run request uses, and no two
+    prompts on one replica share their first token."""
     eng = srv.replicas[0].engine
-    w = 0
+    ids = [0] * len(srv.replicas)          # warm-up first-token ids used, per replica
+
+    def prompts(rows, length):
+        out = []
+        for r in range(len(ids)):
+            out += [(r, warmup_prompt(vocab, ids[r] + i, length)) for i in range(rows)]
+            ids[r] += rows
+        return out
+
     # four tokens: steps right after a page-table push and steps without one
     # take the table as differently placed arguments, and compile apart
     if eng.chunked:
-        n = min(eng.B, 4)
-        await _burst(srv, request_cls, [warmup_prompt(vocab, w + i, 16)
-                                        for i in range(n)], 4)
+        # twice: the first step takes the caches as they were made, so only
+        # the second burst pushes a table beside caches a step has returned
+        for _ in range(2):
+            await _burst(srv, request_cls, prompts(min(eng.B, 4), 16), 4)
         return
     buckets = sorted({eng._bucket(int(n)) for n in sched.prompt_lengths()})
     rows = [1 << i for i in range(eng.B.bit_length()) if 1 << i <= eng.B]
     # the engine's first call takes its caches as they were made, not yet
     # placed on the chip, and compiles apart from every later call of that
     # shape: spend it on an admission of its own before the grid
-    await _burst(srv, request_cls, [warmup_prompt(vocab, w, buckets[0])], 1)
-    w += 1
+    await _burst(srv, request_cls, prompts(1, buckets[0]), 1)
     for b in buckets:
         for r in rows:
-            await _burst(srv, request_cls,
-                         [warmup_prompt(vocab, w + i, b) for i in range(r)], 1)
-            w += r
-    await _burst(srv, request_cls, [warmup_prompt(vocab, w, 8)], 4)
+            await _burst(srv, request_cls, prompts(r, b), 1)
+    await _burst(srv, request_cls, prompts(1, 8), 4)
+
+
+async def _send_history(srv, request_cls, sched) -> int:
+    """Sessions: send the history of every client's opening session at once,
+    through the router, for one token each, so that each replica caches the
+    conversations the router places on it; returns the tokens sent."""
+    hist = [h for h in (sched.history(c) for c in range(sched.clients)) if len(h)]
+
+    async def one(p):
+        async for ev in srv.submit(request_cls(prompt=p.tolist(), max_new=1)):
+            if ev.kind == "error":
+                raise RuntimeError(f"history request failed: {ev.error}")
+
+    await asyncio.gather(*(one(p) for p in hist))
+    return sum(len(h) for h in hist)
 
 
 def _sample(recs: List[C.Record], seed: int, want_tokens: int, max_requests: int):
@@ -149,28 +196,28 @@ def _sample(recs: List[C.Record], seed: int, want_tokens: int, max_requests: int
     return out
 
 
-def logit_gaps(tree, c: dict, seed: int, samples, control: bool = False) -> dict:
+def logit_gaps(tree, ref, c: dict, seed: int, samples, control: bool = False) -> dict:
     """Over every served token of the sample, how far the reference's logit of
     the served token lies below the reference's best at that position: the
-    widest such gap and the mean. The reference runs at the configuration's
-    precision, calibrated on the seed's calibration tokens. With ``control``
-    the same for the token that the control (the reference at int4) puts
-    first at each position."""
+    widest such gap and the mean. The reference (``ref``, the configuration's
+    model module) runs at the configuration's precision, calibrated on the
+    seed's calibration tokens. With ``control`` the same for the token that
+    the control (the reference at int4) puts first at each position."""
     cal = weights.calibration_tokens(c, seed)
     bits = c["quant"]["bits"]
-    cols = reference.calibrate(tree, cal, c, bits)
-    cols4 = reference.calibrate(tree, cal, c, 4) if control else None
+    cols = ref.calibrate(tree, cal, c, bits)
+    cols4 = ref.calibrate(tree, cal, c, 4) if control else None
     gaps, ctl = [], []
     for r in samples:
         p, toks = len(r.prompt), np.asarray(r.tokens, np.int32)
         seq = np.concatenate([r.prompt, toks[:-1]])
         rows = np.arange(p - 1, p - 1 + len(toks))
-        ref = np.asarray(reference.logits(tree, seq, rows, c, bits, cols), np.float64)
-        best = ref.max(-1)
-        gaps.append(best - ref[np.arange(len(toks)), toks])
+        lg = np.asarray(ref.logits(tree, seq, rows, c, bits, cols), np.float64)
+        best = lg.max(-1)
+        gaps.append(best - lg[np.arange(len(toks)), toks])
         if control:
-            pick = np.asarray(reference.logits(tree, seq, rows, c, 4, cols4)).argmax(-1)
-            ctl.append(best - ref[np.arange(len(toks)), pick])
+            pick = np.asarray(ref.logits(tree, seq, rows, c, 4, cols4)).argmax(-1)
+            ctl.append(best - lg[np.arange(len(toks)), pick])
     g = np.concatenate(gaps) if gaps else np.zeros(0)
     out = {"max_logit_gap": float(g.max()) if len(g) else float("inf"),
            "mean_logit_gap": float(g.mean()) if len(g) else float("inf"),
@@ -231,7 +278,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     c0, n00, h0, m0 = clock.total, clock.count, clock.hits, clock.misses
     marks: dict = {}
     c = cell.config
-    cfg, qparams = model.build(c, seed, marks, clock)
+    cfg, qparams = model.build(c, cell.model.layout(c), seed, marks, clock)
 
     from repro.core import qlinear as ql
     from repro.serving.api import Request
@@ -239,7 +286,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
     from repro.serving.server import AsyncServer
 
     econf = EngineConfig(**cell.traffic["engine"])
-    sched = Schedule(cell.traffic, c["vocab"], seed)
+    sched = schedule(cell.traffic, c["vocab"], seed)
     state: dict = {}
     mem: dict = {}
 
@@ -252,20 +299,25 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         await _warm_up(srv, Request, sched, c["vocab"])
         marks["warmup_s"], marks["warmup_compile_s"] = (time.monotonic() - t,
                                                         clock.total - k)
-        eng = srv.replicas[0].engine
-        before = dict(eng.counters)
-        n0 = clock.count
+        if sched.loop == "sessions":
+            t = time.monotonic()
+            sent = await _send_history(srv, Request, sched)
+            marks["history_s"] = time.monotonic() - t
+            log(f"bench: the sessions' history, sent in set-up: {sent} tokens")
+        opened = time.monotonic()
         tr: dict = {}
 
         async def tracer(window):
             await asyncio.sleep(TRACE_AT * seconds)
             tr["dir"] = tempfile.mkdtemp(prefix="bench-trace-")
             opts = jax.profiler.ProfileOptions()
-            opts.python_tracer_level = 1
+            # the program's spans (TraceAnnotation) describe the host; the
+            # Python tracer would add an event per call and inflate host work
+            opts.python_tracer_level = 0
             jax.profiler.start_trace(tr["dir"], profiler_options=opts)
-            tr["t0"] = time.monotonic()
+            tr["t0"], tr["c0"] = time.monotonic(), engine_counters(srv)
             await asyncio.sleep(min(TRACE_MAX_S, 0.3 * seconds))
-            tr["t1"] = time.monotonic()
+            tr["t1"], tr["c1"] = time.monotonic(), engine_counters(srv)
             jax.profiler.stop_trace()
 
         tasks = []
@@ -282,6 +334,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
 
         def on_open(window):
             state["setup_s"] = window.start - t_process
+            if sched.loop == "sessions":
+                marks["opening_s"] = window.start - opened
+            state.update(before=engine_counters(srv), srv_before=server_counters(srv),
+                         n0=clock.count)
             tasks.append(asyncio.ensure_future(memory(window)))
             if traced:
                 tasks.append(asyncio.ensure_future(tracer(window)))
@@ -289,14 +345,16 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         recs, window, lateness = await C.drive(srv, Request, sched, seconds, on_open)
         await asyncio.gather(*tasks)
         state.update(recs=recs, window=window, lateness=lateness,
-                     counters=_delta(before, dict(eng.counters)),
-                     compiles=clock.names[n0:], tr=tr)
+                     counters=_delta(state["before"], engine_counters(srv)),
+                     server=_delta(state["srv_before"], server_counters(srv)),
+                     compiles=clock.names[state["n0"]:], tr=tr)
         await srv.aclose()
 
     asyncio.run(serve())
     mem_peak = max(mem.values(), default=None)
     life = [(d.memory_stats() or {}).get("peak_bytes_in_use") for d in devs[: cell.chips]]
-    log(f"bench: device memory: window peak in use {mem_peak} bytes (read every "
+    log(f"bench: device memory: window peak in use {mem_peak} bytes on the fullest "
+        f"chip, per chip {[mem.get(i) for i in range(cell.chips)]} (read every "
         f"{MEMORY_EVERY_S} s); process peak {max((m for m in life if m), default=None)} "
         f"bytes (the build holds the bfloat16 and int8 trees together)")
     del qparams
@@ -318,27 +376,35 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool, *,
         f"{sum(r.finished for r in recs)}, errors {sum(r.error is not None for r in recs)}")
     log("bench: engine counters over the window: " + ", ".join(
         f"{k} {v}" for k, v in sorted(state["counters"].items()) if v))
+    log("bench: server counters over the window: " + ", ".join(
+        f"{k} {v}" for k, v in sorted(state["server"].items()) if v))
 
     trace_obj = tw = None
+    trace_counters: dict = {}
     if traced:
         tr = state["tr"]
         tw = C.Window(tr["t0"], tr["t1"])
+        trace_counters = _delta(tr["c0"], tr["c1"])
         trace_obj = T.reduce(tr["dir"], tw.seconds)
         shutil.rmtree(tr["dir"], ignore_errors=True)
     run = Run(cell=cell, recs=recs, window=window, setup_s=state["setup_s"],
-              counters=state["counters"], trace=trace_obj, trace_window=tw, peaks=pk)
+              counters=state["counters"], server=state["server"], trace=trace_obj,
+              trace_window=tw, trace_counters=trace_counters, peaks=pk)
 
     # ---- correctness: the reference over a sample of served requests
     t = time.monotonic()
     corr = limits["correct"]
     samples = _sample(recs, seed, corr["sample_tokens"], corr["max_requests"])
-    tree = weights.make(c, seed)
-    gaps = logit_gaps(tree, c, seed, samples, control=control)
+    tree = weights.make(cell.model.layout(c), seed)
+    gaps = logit_gaps(tree, cell.model, c, seed, samples, control=control)
     del tree
     gc.collect()
-    log(f"bench: reference over {len(samples)} requests, {gaps['tokens']} served "
-        f"tokens, {gaps['not_argmax']} of them not the reference's argmax, "
-        f"{time.monotonic() - t:.3f} s")
+    log(f"bench: reference over {len(samples)} requests (finished on replicas "
+        f"{sorted({r.replica for r in samples if r.finished})}, "
+        f"{sum(not r.finished for r in samples)} in flight at the close; longest context "
+        f"{max((len(r.prompt) + len(r.tokens) for r in samples), default=0)}), "
+        f"{gaps['tokens']} served tokens, {gaps['not_argmax']} of them not the "
+        f"reference's argmax, {time.monotonic() - t:.3f} s")
     log(f"bench: served tokens: max_logit_gap {gaps['max_logit_gap']!r}, "
         f"mean_logit_gap {gaps['mean_logit_gap']!r}")
     if control:

@@ -1,11 +1,20 @@
-"""Cells, configurations, traffic mixes and metric readers, found by name.
+"""Cells, configurations, model modules, traffic mixes and metric readers,
+found by name.
 
 ``BENCHMARK.json`` at the checkout's root names every cell. A cell's
-configuration is ``bench/configs/<config>.json`` (through the ``file`` key), its
-traffic mix ``bench/traffic/<traffic>.json``, and each metric it reports a
-reader ``bench/metrics/<metric>.py`` with a function ``read(run)``. Adding a
-cell, a configuration, a mix or a metric therefore means adding files and
-entries; no code here changes.
+configuration is ``bench/configs/<config>.json`` (through the ``file`` key); the
+configuration's ``"model"`` key names its model module
+``bench/models/<model>.py``, which gives the weight layout, the plain
+reference, the operations per token and the paged attention's shape (see
+``bench/models/decoder.py``). The cell's traffic mix is
+``bench/traffic/<traffic>.json``, its limits ``bench/limits/<cell>.json``, and
+each metric it reports a reader ``bench/metrics/<metric>.py`` with a function
+``read(run)``.
+
+Adding a cell, a configuration, a mix or a metric therefore means adding files
+and entries; no code here changes. A new architecture is a new configuration
+file whose ``"model"`` names a new ``bench/models/<name>.py``, beside a mix and
+limits for its cell: no existing file of the benchmark is edited.
 """
 from __future__ import annotations
 
@@ -13,6 +22,7 @@ import dataclasses
 import importlib.util
 import json
 import pathlib
+import types
 from typing import Callable, Dict, List
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
@@ -25,6 +35,7 @@ class Cell:
     chips: int
     config_name: str
     config: dict          # the configuration file's contents
+    model: types.ModuleType  # the configuration's model module
     traffic_name: str
     traffic: dict         # the traffic file's contents
     end_to_end: List[dict]
@@ -54,18 +65,32 @@ def load_cell(name: str, root: pathlib.Path = ROOT) -> Cell:
     with open(root / "bench" / "traffic" / f"{w['traffic']}.json") as f:
         traffic = json.load(f)
     return Cell(name=name, chips=int(w["chips"]), config_name=w["config"],
-                config=config, traffic_name=w["traffic"], traffic=traffic,
+                config=config, model=model_module(config["model"], root),
+                traffic_name=w["traffic"], traffic=traffic,
                 end_to_end=[m for m in bench["end_to_end"] if _applies(m, name)],
                 per_layer=[m for m in bench["per_layer"] if _applies(m, name)])
 
 
-def reader(metric: str, root: pathlib.Path = ROOT) -> Callable:
-    """``read(run)`` of ``bench/metrics/<metric>.py``."""
-    path = root / "bench" / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
+def _load(path: pathlib.Path, name: str) -> types.ModuleType:
+    if not path.is_file():
+        raise FileNotFoundError(f"no module {path}")
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def model_module(name: str, root: pathlib.Path = ROOT) -> types.ModuleType:
+    """``bench/models/<name>.py``: ``layout``, ``calibrate``, ``logits``,
+    ``work`` and ``attention`` of one architecture. Each call executes the
+    file anew; a cell keeps the module it was loaded with, and with it the
+    reference's compiled programs."""
+    return _load(root / "bench" / "models" / f"{name}.py", f"bench_model_{name}")
+
+
+def reader(metric: str, root: pathlib.Path = ROOT) -> Callable:
+    """``read(run)`` of ``bench/metrics/<metric>.py``."""
+    return _load(root / "bench" / "metrics" / f"{metric}.py", f"bench_metric_{metric}").read
 
 
 def read_metrics(metrics: List[dict], run, root: pathlib.Path = ROOT) -> Dict[str, dict]:

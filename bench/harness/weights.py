@@ -1,16 +1,15 @@
 """Random weights from the seed, made by the benchmark.
 
 One jitted call draws every leaf on the device and casts it to bfloat16 there,
-so no float32 tree is ever held. The layout is the one the serving program
-takes (stacked per-layer leaves under ``blocks/0``); the plain reference reads
-the same tree by name. The draw is the benchmark's own: leaf ``k`` in sorted
-path order comes from ``fold_in(key, k)``, with the scales the program's own
-initializer uses (embedding 0.02, a linear ``d_in ** -0.5``, norms at one and
-zero), so the reference can regenerate the weights without the program.
+so no float32 tree is ever held. The layout (path -> shape, kind, scale) is the
+model module's (``bench/models/<model>.py``, ``layout(c)``), the one the
+serving program takes; the plain reference reads the same tree by name. The
+draw is the benchmark's own: leaf ``k`` in sorted path order comes from
+``fold_in(key, k)``, so the reference can regenerate the weights without the
+program. A path segment of digits under a group makes that group a list
+(``blocks/0`` -> ``blocks[0]``).
 """
 from __future__ import annotations
-
-from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -18,48 +17,6 @@ import jax.numpy as jnp
 
 def vocab_padded(c: dict) -> int:
     return -(-c["vocab"] // 256) * 256
-
-
-def layout(c: dict) -> Dict[str, Tuple[tuple, str, float]]:
-    """path -> (shape, kind, scale); kind is ``normal``, ``ones`` or ``zeros``."""
-    L, d, D = c["n_layers"], c["d_model"], c["head_dim"]
-    hq, hkv = c["n_heads"] * D, c["n_kv_heads"] * D
-    out = {"embed/w": ((vocab_padded(c), d), "normal", 0.02)}
-
-    def norm(prefix, stacked):
-        shape = (L, d) if stacked else (d,)
-        out[f"{prefix}/scale"] = (shape, "ones", 0.0)
-        if c["norm"] == "layernorm":
-            out[f"{prefix}/bias"] = (shape, "zeros", 0.0)
-
-    def lin(path, d_in, d_out, lead=(L,)):
-        out[f"{path}/w"] = (lead + (d_in, d_out), "normal", d_in ** -0.5)
-
-    b = "blocks/0"
-    norm(f"{b}/norm1", True)
-    norm(f"{b}/norm2", True)
-    lin(f"{b}/attn/wq", d, hq)
-    lin(f"{b}/attn/wk", d, hkv)
-    lin(f"{b}/attn/wv", d, hkv)
-    lin(f"{b}/attn/wo", hq, d)
-    glu = c["act"].endswith("_glu")
-    if c.get("n_experts"):
-        E, f = c["n_experts"], c["d_ff_expert"]
-        out[f"{b}/moe/router/w"] = ((L, d, E), "normal", d ** -0.5)
-        lin(f"{b}/moe/up", d, f, (L, E))
-        lin(f"{b}/moe/down", f, d, (L, E))
-        if glu:
-            lin(f"{b}/moe/gate", d, f, (L, E))
-    else:
-        f = c["d_ff"]
-        lin(f"{b}/mlp/up", d, f)
-        lin(f"{b}/mlp/down", f, d)
-        if glu:
-            lin(f"{b}/mlp/gate", d, f)
-    norm("final_norm", False)
-    if not c.get("tie_embeddings", True):
-        lin("lm_head", d, vocab_padded(c), ())
-    return out
 
 
 def key_from_seed(seed: int) -> jax.Array:
@@ -81,6 +38,16 @@ def calibration_tokens(c: dict, seed: int):
     return np.random.default_rng([int(seed), 3]).integers(1, c["vocab"], (rows, n))
 
 
+def _lists(node):
+    """Groups keyed by digits only become lists, in the order of the digits."""
+    if not isinstance(node, dict):
+        return node
+    out = {k: _lists(v) for k, v in node.items()}
+    if out and all(k.isdigit() for k in out):
+        return [out[k] for k in sorted(out, key=int)]
+    return out
+
+
 def _nest(flat: dict) -> dict:
     tree: dict = {}
     for path, v in flat.items():
@@ -89,13 +56,12 @@ def _nest(flat: dict) -> dict:
         for p in parts[:-1]:
             node = node.setdefault(p, {})
         node[parts[-1]] = v
-    tree["blocks"] = [tree["blocks"]["0"]]
-    return tree
+    return _lists(tree)
 
 
-def make(c: dict, seed: int) -> dict:
-    """The bfloat16 weight tree of configuration ``c`` for ``seed``."""
-    lay = layout(c)
+def make(lay: dict, seed: int) -> dict:
+    """The bfloat16 weight tree of layout ``lay`` (a model module's
+    ``layout(c)``) for ``seed``."""
     paths = sorted(lay)
 
     def draw(key):

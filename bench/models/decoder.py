@@ -1,4 +1,26 @@
-"""The plain reference: the configuration's forward pass, in float32 arithmetic.
+"""Model module ``decoder``: a pre-norm decoder of GQA + RoPE attention and a
+dense or mixture-of-experts MLP, with no biases beyond LayerNorm's and no
+shared expert (granite-3.0 MoE, starcoder2).
+
+A configuration file names its model module with the key ``"model"``; the
+harness loads ``bench/models/<model>.py`` by that name (``spec.model_module``)
+and reads everything that depends on the architecture from it:
+
+- ``layout(c)``: path -> (shape, kind, scale) of every weight leaf, the layout
+  the serving program takes; ``weights.make`` draws the leaves from the seed;
+- ``calibrate(tree, tokens, c, bits)`` and ``logits(tree, seq, rows, c, bits,
+  cols)``: the plain reference, below;
+- ``work(c)``: the operations one token needs (``flops.ModelWork``), which
+  the ``mfu.*`` readers count;
+- ``attention(c)``: the paged attention layers' shape, from which
+  ``flops.paged_decode`` counts the cached-context bytes per token that
+  ``paged_decode_roofline`` reads; None for a model without them.
+
+To add an architecture, add a configuration file that names a new module and
+the module ``bench/models/<name>.py`` with these five functions: no other file
+of the harness changes. A traffic mix and the cell's limits are files too.
+
+The plain reference: the configuration's forward pass, in float32 arithmetic.
 
 Straight ``jax.numpy`` at ``Precision.HIGHEST``, no cache, no batching, no
 kernels, and nothing imported from the program. It takes the bfloat16 weights
@@ -31,6 +53,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from bench.harness import flops
+from bench.harness.weights import vocab_padded
 
 HI = lax.Precision.HIGHEST
 EPS = 1e-8
@@ -231,6 +256,12 @@ def _round_up(n: int, m: int) -> int:
     return m * math.ceil(n / m)
 
 
+def _padded_length(n: int) -> int:
+    """``T_BLOCK`` or the next power of two: few shapes to compile, each a
+    multiple of the attention and expert blocks."""
+    return max(T_BLOCK, 1 << (n - 1).bit_length())
+
+
 def _static(c: dict) -> tuple:
     flat = {k: v for k, v in c.items() if isinstance(v, (int, float, str, bool))}
     flat["alpha"] = float(c.get("quant", {}).get("alpha", 0.15))
@@ -247,13 +278,13 @@ def calibrate(tree, tokens, c: dict, bits):
 def logits(tree, tokens, rows, c: dict, bits=None, cols=None):
     """Float32 logits over the real vocabulary at positions ``rows`` of the
     sequence ``tokens`` (numpy int arrays), at ``bits`` with the calibration
-    tables ``cols``. The sequence is padded at its end to a multiple of
-    ``T_BLOCK`` and ``rows`` to a multiple of 128, which a causal model
+    tables ``cols``. The sequence is padded at its end to ``T_BLOCK`` or the
+    next power of two and ``rows`` to a multiple of 128, which a causal model
     allows; the padding is cut from the result."""
     import numpy as np
 
     T, n = len(tokens), len(rows)
-    tok = np.zeros(_round_up(T, T_BLOCK), np.int32)
+    tok = np.zeros(_padded_length(T), np.int32)
     tok[:T] = tokens
     r = np.zeros(_round_up(n, 128), np.int32)
     r[:n] = rows
@@ -262,3 +293,76 @@ def logits(tree, tokens, rows, c: dict, bits=None, cols=None):
         cols = jax.tree_util.tree_map(lambda z: jnp.zeros((L,) + z.shape), _blank_cols(c))
     return _logits(tree, cols, jnp.asarray(tok), jnp.asarray(r), cfg=_static(c),
                    bits=bits)[:n]
+
+
+def layout(c: dict) -> dict:
+    """path -> (shape, kind, scale); kind is ``normal``, ``ones`` or ``zeros``.
+    Per-layer leaves are stacked under ``blocks/0``, as the program stacks
+    them; the scales are the program's initializer's (embedding 0.02, a
+    linear ``d_in ** -0.5``, norms at one and zero)."""
+    L, d, D = c["n_layers"], c["d_model"], c["head_dim"]
+    hq, hkv = c["n_heads"] * D, c["n_kv_heads"] * D
+    out = {"embed/w": ((vocab_padded(c), d), "normal", 0.02)}
+
+    def norm(prefix, stacked):
+        shape = (L, d) if stacked else (d,)
+        out[f"{prefix}/scale"] = (shape, "ones", 0.0)
+        if c["norm"] == "layernorm":
+            out[f"{prefix}/bias"] = (shape, "zeros", 0.0)
+
+    def lin(path, d_in, d_out, lead=(L,)):
+        out[f"{path}/w"] = (lead + (d_in, d_out), "normal", d_in ** -0.5)
+
+    b = "blocks/0"
+    norm(f"{b}/norm1", True)
+    norm(f"{b}/norm2", True)
+    lin(f"{b}/attn/wq", d, hq)
+    lin(f"{b}/attn/wk", d, hkv)
+    lin(f"{b}/attn/wv", d, hkv)
+    lin(f"{b}/attn/wo", hq, d)
+    glu = c["act"].endswith("_glu")
+    if c.get("n_experts"):
+        E, f = c["n_experts"], c["d_ff_expert"]
+        out[f"{b}/moe/router/w"] = ((L, d, E), "normal", d ** -0.5)
+        lin(f"{b}/moe/up", d, f, (L, E))
+        lin(f"{b}/moe/down", f, d, (L, E))
+        if glu:
+            lin(f"{b}/moe/gate", d, f, (L, E))
+    else:
+        f = c["d_ff"]
+        lin(f"{b}/mlp/up", d, f)
+        lin(f"{b}/mlp/down", f, d)
+        if glu:
+            lin(f"{b}/mlp/gate", d, f)
+    norm("final_norm", False)
+    if not c.get("tie_embeddings", True):
+        lin("lm_head", d, vocab_padded(c), ())
+    return out
+
+
+def _linear_params(c: dict) -> tuple:
+    """(int8 linear weights a token passes through in one block, router
+    weights in one block)."""
+    d, D = c["d_model"], c["head_dim"]
+    hq, hkv = c["n_heads"] * D, c["n_kv_heads"] * D
+    attn = d * (hq + 2 * hkv) + hq * d
+    mats = 3 if c["act"].endswith("_glu") else 2
+    if c.get("n_experts"):
+        return attn + c["top_k"] * mats * d * c["d_ff_expert"], d * c["n_experts"]
+    return attn + mats * d * c["d_ff"], 0
+
+
+def work(c: dict) -> flops.ModelWork:
+    """One token through the model: int8 linears in every block; bf16
+    attention (QK and PV) per attended position, the router, and the head."""
+    lin, router = _linear_params(c)
+    L = c["n_layers"]
+    return flops.ModelWork(int8_ops=2.0 * lin * L, bf16_ops=L * 2.0 * router,
+                           bf16_ops_per_position=L * 4.0 * c["n_heads"] * c["head_dim"],
+                           head_ops=2.0 * c["d_model"] * c["vocab"])
+
+
+def attention(c: dict) -> dict:
+    """Every layer is a paged GQA layer."""
+    return {"layers": c["n_layers"], "n_heads": c["n_heads"],
+            "n_kv_heads": c["n_kv_heads"], "head_dim": c["head_dim"]}

@@ -3,6 +3,8 @@ import json
 
 from bench.harness import flops, spec
 
+decoder = spec.model_module("decoder")
+
 
 # starcoder2-7b's published widths (arXiv:2402.19173) at 16 of its 32 layers: a
 # dense GQA model with a two-matrix MLP
@@ -35,7 +37,7 @@ def test_paged_decode_hand_count():
 
 def test_granite_token_hand_count():
     c = _config("granite-moe-3b-a800m")
-    w = flops.token_work(c, ctx=1)
+    w = flops.token_work(decoder.work(c), ctx=1)
     attn = 1536 * (1536 + 2 * 512) + 1536 * 1536          # q, k, v, o
     experts = 8 * 3 * 1536 * 512                          # top 8 of gate, up, down
     assert attn + experts == 25_165_824
@@ -45,7 +47,7 @@ def test_granite_token_hand_count():
 
 def test_starcoder2_token_hand_count():
     c = _config("starcoder2-7b-l16")
-    w = flops.token_work(c, ctx=1)
+    w = flops.token_work(decoder.work(c), ctx=1)
     per_layer = 4608 * (4608 + 2 * 512) + 4608 * 4608 + 2 * 4608 * 18432
     assert per_layer == 217_055_232
     assert w.int8_ops == 2 * per_layer * 16 == 6_945_767_424
@@ -54,10 +56,19 @@ def test_starcoder2_token_hand_count():
 
 def test_prefill_is_its_tokens():
     c = _config("starcoder2-7b-l16")
-    p = flops.prefill_work(c, prompt=10, reused=0)
-    assert p.int8_ops == 10 * flops.token_work(c, 1).int8_ops
+    p = flops.prefill_work(decoder.work(c), prompt=10, reused=0)
+    assert p.int8_ops == 10 * flops.token_work(decoder.work(c), 1).int8_ops
     # causal: token i attends to i + 1 positions; the head runs once
-    attn = sum(flops.token_work(c, i + 1).bf16_ops for i in range(10))
+    attn = sum(flops.token_work(decoder.work(c), i + 1).bf16_ops for i in range(10))
     head = 2 * c["d_model"] * c["vocab"]
     assert p.bf16_ops == attn - 9 * head
-    assert flops.prefill_work(c, prompt=10, reused=10) == flops.Work()
+    assert flops.prefill_work(decoder.work(c), prompt=10, reused=10) == flops.Work()
+
+
+def test_prompt_rows_are_tokens_at_one_context_without_the_head():
+    m = decoder.work(_config("granite-moe-3b-a800m"))
+    w = flops.prompt_rows_work(m, rows=5, ctx=300)
+    one = flops.token_work(m, 300)
+    assert w.int8_ops == 5 * one.int8_ops
+    assert w.bf16_ops == 5 * (one.bf16_ops - m.head_ops)
+    assert flops.prompt_rows_work(m, rows=0, ctx=300) == flops.Work()

@@ -5,7 +5,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bench.harness import model, reference, weights
+from bench.harness import model, spec, weights
+
+reference = spec.model_module("decoder")
 
 BASE = dict(name="small", n_layers=2, d_model=64, n_heads=4, n_kv_heads=2, head_dim=16,
             vocab=500, tie_embeddings=True, dtype="float32", quant=dict(alpha=0.15))
@@ -26,7 +28,7 @@ def test_layout_is_the_programs(kind):
     c = CONFIGS[kind]
     shapes = jax.eval_shape(lambda k: M.init_params(k, model.program_config(c)),
                             jax.random.PRNGKey(0))
-    tree = weights.make(c, 5)
+    tree = weights.make(reference.layout(c), 5)
     assert jax.tree_util.tree_structure(shapes) == jax.tree_util.tree_structure(tree)
     for a, b in zip(jax.tree_util.tree_leaves(shapes), jax.tree_util.tree_leaves(tree)):
         assert a.shape == b.shape
@@ -39,7 +41,7 @@ def test_float_reference_is_the_programs_float_forward(kind):
     from repro.models.layers import QuantContext
 
     c = CONFIGS[kind]
-    tree = weights.make(c, 5)
+    tree = weights.make(reference.layout(c), 5)
     f32 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), tree)
     toks = np.random.default_rng(0).integers(1, c["vocab"], 40).astype(np.int32)
     with jax.default_matmul_precision("highest"):

@@ -1,6 +1,8 @@
 """A whole run on the CPU at a small size, past the harness's look for a chip:
-the control, judged by the run's own comparison, comes out not correct, a token altered where the engine produces it
-reads not correct, and what the run prints before its result."""
+the control, judged by the run's own comparison, comes out not correct; a token
+altered where the engine produces it reads not correct; what the run prints
+before its result; and multi-turn sessions on four replicas."""
+import json
 import time
 
 import pytest
@@ -8,7 +10,7 @@ import pytest
 from bench.harness import runner, spec
 
 # small dense GQA model of the starcoder2 family, served chunked as the cells are
-CONFIG = dict(name="small", arch="starcoder2-7b", family="dense", n_layers=2,
+CONFIG = dict(name="small", arch="starcoder2-7b", model="decoder", family="dense", n_layers=2,
               d_model=128, n_heads=4, n_kv_heads=2, head_dim=32, d_ff=256, vocab=512,
               n_experts=0, act="gelu", norm="layernorm", rope_theta=1e6,
               tie_embeddings=True, dtype="bfloat16",
@@ -30,8 +32,8 @@ SEED = 2**31 + 99
 def _cell():
     b = spec.load_benchmark()
     return spec.Cell(name="small", chips=1, config_name="small", config=CONFIG,
-                     traffic_name="small", traffic=TRAFFIC, end_to_end=b["end_to_end"],
-                     per_layer=b["per_layer"])
+                     model=spec.model_module("decoder"), traffic_name="small",
+                     traffic=TRAFFIC, end_to_end=b["end_to_end"], per_layer=b["per_layer"])
 
 
 def _run(**kw):
@@ -91,3 +93,88 @@ def test_token_altered_where_produced_is_not_correct(monkeypatch):
     out = _run()
     assert out["correct"] is False
     assert out["check"]["max_logit_gap"]["value"] > LIMITS["correct"]["limits"]["max_logit_gap"]
+
+
+# multi-turn sessions on four replicas behind the affinity router, each
+# replica pinned to its own virtual CPU device as the sessions cell pins one to
+# each chip; run in a child process, which can be given four devices
+SESSIONS = dict(loop="sessions", clients=8, n_sessions=64, block=8, stagger=True,
+                sizes_seed=5,
+                turns=dict(dist="uniform", lo=2, hi=3),
+                first=dict(dist="uniform", lo=8, hi=24),
+                user=dict(dist="uniform", lo=4, hi=12),
+                output=dict(dist="uniform", lo=4, hi=8),
+                engine=dict(batch_size=2, max_len=96, chunked=True, token_budget=32,
+                            kv_cache="int8", cache_layout="paged", path="fused-int8",
+                            scheduler="continuous", prefix_reuse=True))
+
+
+def _sessions_run():
+    b = spec.load_benchmark()
+    cell = spec.Cell(name="small.sessions", chips=4, config_name="small", config=CONFIG,
+                     model=spec.model_module("decoder"), traffic_name="sessions",
+                     traffic=SESSIONS, end_to_end=b["end_to_end"],
+                     per_layer=b["per_layer"])
+    return runner.run_cell(cell, SEED, 5.0, False, t_process=time.monotonic(),
+                           require_tpu=False, limits=LIMITS)
+
+
+def _child(mode: str):
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.pathsep.join([str(spec.ROOT), str(spec.ROOT / "src")]))
+    p = subprocess.run([sys.executable, __file__, mode], env=env, capture_output=True,
+                       text=True, timeout=600)
+    assert p.returncode == 0, p.stderr[-3000:]
+    return json.loads(p.stdout.strip().splitlines()[-1]), p.stderr
+
+
+def test_sessions_on_four_replicas_are_correct_and_reuse_their_history():
+    out, err = _child("sound")
+    assert out["correct"] is True and out["failed"] == 0
+    assert out["device"]["count"] == 4
+    assert {"output_tok_s", "itl_p95_ms", "setup_s", "ttft_p95_ms"} <= set(out["metrics"])
+    assert "bench: compiles inside the window: 0" in err
+    # the history of the clients' opening sessions went out in set-up, and the
+    # window opened after the opening turns' first tokens
+    sent = next(ln for ln in err.splitlines() if "history, sent in set-up" in ln)
+    assert int(sent.rsplit(":", 1)[1].split()[0]) > 0
+    assert "opening_s" in next(ln for ln in err.splitlines() if ln.startswith("bench: set-up"))
+    line = next(ln for ln in err.splitlines() if "server counters over the" in ln)
+    assert "affinity_hits" in line
+    assert "prefix_tokens_reused" in next(
+        ln for ln in err.splitlines() if "engine counters over the" in ln)
+    # the sample is drawn across the replicas
+    used = next(ln for ln in err.splitlines() if ln.startswith("bench: reference over"))
+    reps = used.split("finished on replicas [", 1)[1].split("]", 1)[0]
+    assert len(reps.split(",")) > 1
+
+
+def test_sessions_token_altered_where_produced_is_not_correct():
+    out, _ = _child("altered")
+    assert out["correct"] is False
+    assert out["check"]["max_logit_gap"]["value"] > LIMITS["correct"]["limits"]["max_logit_gap"]
+
+
+def _altered_emit():
+    from repro.serving import engine as E
+
+    emit = E.ServeEngine._emit
+
+    def altered(self, slot, tok, finished):
+        if len(self._slots[slot].out) == 2:       # the third token of every answer
+            tok = (tok + 1) % CONFIG["vocab"]
+        return emit(self, slot, tok, finished)
+
+    E.ServeEngine._emit = altered
+
+
+if __name__ == "__main__":
+    import sys
+
+    if sys.argv[1] == "altered":
+        _altered_emit()
+    print(json.dumps(_sessions_run()), flush=True)
